@@ -84,32 +84,6 @@ def rows():
         ),
     })
 
-    # ISGD streaming update: E events over (U+I) tables. The VMEM-resident
-    # kernel pays one whole-table HBM round-trip per micro-batch, vs the
-    # naive lowering's per-event gather/scatter; the crossover sits at
-    # E ~ (U_cap + I_cap) / 2 — both sides of it are shown.
-    u_cap, i_cap = 4096, 2048
-    for e in (1024, 16384):
-        ut = jnp.asarray(rng.normal(size=(u_cap, k)), jnp.float32)
-        itab = jnp.asarray(rng.normal(size=(i_cap, k)), jnp.float32)
-        us_ = jnp.asarray(rng.integers(0, u_cap, e), jnp.int32)
-        is_ = jnp.asarray(rng.integers(0, i_cap, e), jnp.int32)
-        val = jnp.ones((e,), bool)
-        ref_fn = jax.jit(lambda a, b2, c, d, f: ref.isgd_apply(
-            a, b2, c, d, f, eta=0.05, lam=0.01))
-        us = _time(ref_fn, ut, itab, us_, is_, val)
-        naive_bytes = e * 4 * 4 * k          # per-event gather+scatter
-        kernel_bytes = 4 * 2 * (u_cap + i_cap) * k  # one table round-trip
-        out.append({
-            "name": f"kernel/isgd/E{e}_U{u_cap}_I{i_cap}_k{k}",
-            "us_per_call": us,
-            "derived": (
-                f"tpu_hbm_us_naive={naive_bytes / hw.hbm_bw * 1e6:.2f}"
-                f" tpu_hbm_us_vmem_resident={kernel_bytes / hw.hbm_bw * 1e6:.2f}"
-                f" traffic_saving={naive_bytes / kernel_bytes:.2f}x"
-            ),
-        })
-
     # SWA flash attention: prefill tile.
     b2, hq, hkv, s, d = 1, 8, 2, 2048, 128
     window = 512

@@ -72,6 +72,9 @@ def main() -> None:
                     help="tiny throughput check, writes BENCH_smoke.json")
     ap.add_argument("--smoke-out", default="BENCH_smoke.json")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if args.smoke:
         raise SystemExit(smoke(args.smoke_out))
     only = set(args.only.split(",")) if args.only else set(SUITES)

@@ -80,6 +80,8 @@ def bpr_worker_step(state: DisgdState, events, hyper: BprHyper,
     bookkeeping as ``disgd_worker_step`` — only the training rule
     differs (pairwise BPR step on a sampled local negative).
     """
+    from repro.kernels import ops
+
     u_ids, i_ids = events
     init_us = jax.vmap(
         lambda ident: init_vector(key, ident, hyper.k, hyper.init_scale)
@@ -154,8 +156,8 @@ def bpr_worker_step(state: DisgdState, events, hyper: BprHyper,
             item_ts=t.item_ts.at[wi].set(clock, mode="drop"),
             clock=clock,
         )
-        rated = st.rated.at[:, jnp.where(w & new_i, i_slot, hyper.i_cap)].set(
-            jnp.zeros_like(st.rated[:, 0]), mode="drop")
+        (rated,) = ops.clear_columns(st.rated, i_slot[None],
+                                     (w & new_i)[None])
         row = jnp.where(w & new_u, False, rated[u_slot])
         row = row.at[jnp.where(w, i_slot, hyper.i_cap)].set(True, mode="drop")
         rated = rated.at[wu].set(row, mode="drop")

@@ -104,8 +104,12 @@ def disgd_worker_step(state: DisgdState, events, hyper: DisgdHyper, key: jax.Arr
     both branches — i.e. a full copy of every factor table and the rated
     bitmap *per event*. Masked scatters keep each scan iteration O(rows
     touched), which is the difference between the step being copy-bound
-    and compute-bound.
+    and compute-bound. The eviction's column clear is one in-place column
+    update (``ops.clear_columns``), not a column scatter, for which XLA on
+    the TPU transposes the whole rated table twice per event.
     """
+    from repro.kernels import ops
+
     u_ids, i_ids = events
     # Replica-consistent init vectors for the whole bucket in one batched
     # PRNG pass (fold_in per id, so values are identical to per-event
@@ -166,8 +170,8 @@ def disgd_worker_step(state: DisgdState, events, hyper: DisgdHyper, key: jax.Arr
         # Collision eviction: clear the evicted item's column, then the
         # evicted user's row, then mark the rated pair (same order as the
         # hash-map semantics; no-ops when capacity covers the id space).
-        rated = st.rated.at[:, jnp.where(w & new_i, i_slot, hyper.i_cap)].set(
-            jnp.zeros_like(st.rated[:, 0]), mode="drop")
+        (rated,) = ops.clear_columns(st.rated, i_slot[None],
+                                     (w & new_i)[None])
         row = jnp.where(w & new_u, False, rated[u_slot])
         row = row.at[jnp.where(w, i_slot, hyper.i_cap)].set(True, mode="drop")
         rated = rated.at[wu].set(row, mode="drop")

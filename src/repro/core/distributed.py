@@ -17,18 +17,15 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
-
-try:  # jax >= 0.5 exposes shard_map at top level
-    from jax import shard_map
-except ImportError:  # jax 0.4.x
-    from jax.experimental.shard_map import shard_map
 
 from repro.core import algorithm as algorithm_lib
 from repro.core.pipeline import StreamConfig
 from repro.core.routing import GridSpec
 
 __all__ = [
+    "flat_worker_sharding",
     "grid_axes",
     "grid_from_mesh",
     "make_grid_step",
@@ -36,16 +33,6 @@ __all__ = [
     "init_grid_states",
     "grid_state_specs",
 ]
-
-
-def _shard_map_nocheck(f, *, mesh, in_specs, out_specs):
-    """shard_map with replication checking off (kwarg renamed across jax)."""
-    try:
-        return shard_map(f, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_vma=False)
-    except TypeError:
-        return shard_map(f, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
 
 
 def grid_axes(mesh):
@@ -66,6 +53,18 @@ def grid_from_mesh(mesh) -> GridSpec:
     n_i = mesh.shape[item_ax]
     g = int(np.prod([mesh.shape[a] for a in user_axes]))
     return GridSpec.rect(n_i, g)
+
+
+def flat_worker_sharding(mesh):
+    """Sharding of worker-major ``[n_c, ...]`` states on a grid mesh.
+
+    Worker ``key = row * g + col`` lives at mesh coordinate
+    ``(model=row, data=col)``: the flat axis splits over ``model`` then
+    the user axes, which is the layout ``make_flat_grid_worker`` reshapes
+    to ``(n_i, g)`` without moving data.
+    """
+    item_ax, user_axes = grid_axes(mesh)
+    return jax.sharding.NamedSharding(mesh, P((item_ax,) + user_axes))
 
 
 def init_grid_states(cfg: StreamConfig, mesh):
@@ -112,11 +111,12 @@ def _make_grid_step_unjitted(cfg: StreamConfig, mesh):
             ev[None, None],
         )
 
-    return _shard_map_nocheck(
+    return shard_map(
         local,
         mesh=mesh,
         in_specs=(state_spec, ev_spec, ev_spec),
         out_specs=(state_spec, ev_spec, ev_spec),
+        check_vma=False,
     )
 
 

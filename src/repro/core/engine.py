@@ -65,7 +65,7 @@ from repro.drift import detector as detector_lib
 from repro.obs import telemetry as telemetry_lib
 
 __all__ = ["make_worker_fn", "make_pallas_worker_fn", "run_stream_device",
-           "PublishEvent"]
+           "PublishEvent", "scan_steps", "compiled_scan"]
 
 
 def make_worker_fn(cfg) -> Callable:
@@ -330,14 +330,27 @@ def init_scan_carry(cfg, states=None, carry=(None, None), detector=None):
             telemetry_lib.telemetry_init(cfg.grid.n_c))
 
 
+def scan_steps(cfg, n_events: int, resumed_carry: bool = False) -> int:
+    """Scan steps one ``run_stream_device`` call takes for ``n_events``:
+    the micro-batches plus a static drain tail (worst case, every carried
+    event targets one worker)."""
+    n_batches = int(np.ceil(n_events / cfg.micro_batch)) if n_events else 0
+    carry_cap = cfg.carry_slots or cfg.micro_batch
+    drain = (int(np.ceil(carry_cap / cfg.bucket_capacity))
+             if (n_batches or resumed_carry) else 0)
+    return n_batches + drain
+
+
 @functools.lru_cache(maxsize=16)
-def _compiled_scan(cfg, steps: int):
-    """AOT-compiled scan executable for (config, step count)."""
+def compiled_scan(cfg, steps: int):
+    """AOT-compiled scan executable for (config, step count) — the one
+    ``run_stream_device`` runs (without an explicit mesh). Lowered from
+    shapes only, so compiling allocates no state."""
     worker_fn = _resolve_worker_fn(cfg)
     batch_step, _, _ = _make_batch_step(cfg, worker_fn)
-    carry0 = init_scan_carry(cfg)
+    carry0 = jax.eval_shape(lambda: init_scan_carry(cfg))
     mb = cfg.micro_batch
-    xs = (jnp.zeros((steps, mb), jnp.int32), jnp.zeros((steps, mb), jnp.int32))
+    xs = (jax.ShapeDtypeStruct((steps, mb), jnp.int32),) * 2
     run = jax.jit(lambda c, x: jax.lax.scan(batch_step, c, x))
     return run.lower(carry0, xs).compile()
 
@@ -435,15 +448,11 @@ def run_stream_device(users: np.ndarray, items: np.ndarray, cfg,
     assert users.shape == items.shape
     n = users.shape[0]
     mb = cfg.micro_batch
-    carry_cap = cfg.carry_slots or mb
-    cap = cfg.bucket_capacity
 
     resumed_carry = (initial_carry[0] is not None
                      and np.asarray(initial_carry[0]).size > 0)
     n_batches = int(np.ceil(n / mb)) if n else 0
-    # Static drain tail: worst case every carried event targets one worker.
-    drain = int(np.ceil(carry_cap / cap)) if (n_batches or resumed_carry) else 0
-    steps = n_batches + drain
+    steps = scan_steps(cfg, n, resumed_carry)
 
     seg = publish_every if publish_every > 0 else max(steps, 1)
     n_segments = int(np.ceil(steps / seg))
@@ -466,7 +475,7 @@ def run_stream_device(users: np.ndarray, items: np.ndarray, cfg,
     # the executable; mesh objects are unhashable, so explicit-mesh
     # shard_map runs compile per call.
     if mesh is None and cfg.backend != "shard_map":
-        compiled = _compiled_scan(cfg, seg)
+        compiled = compiled_scan(cfg, seg)
     else:
         worker_fn = _resolve_worker_fn(cfg, mesh=mesh)
         batch_step, _, _ = _make_batch_step(cfg, worker_fn)

@@ -182,7 +182,19 @@ def init_states(cfg: StreamConfig):
     # applied here, once, before the broadcast over workers.
     one = storage_lib.encode_state(one, cfg.storage)
     n_c = cfg.grid.n_c
-    return jax.tree.map(lambda x: jnp.broadcast_to(x, (n_c,) + x.shape), one)
+
+    def stack(one):
+        return jax.tree.map(lambda x: jnp.broadcast_to(x, (n_c,) + x.shape),
+                            one)
+
+    if cfg.backend != "shard_map":
+        return stack(one)
+    # One worker per device of the grid mesh, built in place there.
+    from repro.core import distributed
+    from repro.launch.mesh import make_grid_mesh
+
+    sharding = distributed.flat_worker_sharding(make_grid_mesh(cfg.grid))
+    return jax.jit(stack, out_shardings=sharding)(one)
 
 
 def run_stream(users: np.ndarray, items: np.ndarray, cfg: StreamConfig,

@@ -1,10 +1,16 @@
 """Pallas TPU kernel: streaming DICS co-occurrence micro-batch update.
 
-Sequential-grid sibling of ``factor_update.py`` for the neighborhood
-model: one grid step per event scatters the user's rating history into
-the co-rating matrix (Eq. 6 numerator statistics), bumps the item
-support count, and maintains the rated bitmap plus the id/freq/ts
-tables — all VMEM-resident for the micro-batch.
+Sibling of ``factor_update.py`` for the neighborhood model: per event it
+adds the user's rating history into the co-rating matrix's row and
+column of the event's item (Eq. 6 numerator statistics) and maintains
+the user's rated row. The grid axis is the worker.
+
+``co`` stays in HBM (``memory_space=ANY``, aliased in place): per event
+the kernel DMAs the 8-row tile holding the item's row and the 128-lane
+tile holding its column, updates them in VMEM and writes them back —
+O(8·I + 128·I) bytes per event however large the worker's split is.
+The user rated rows arrive as compact blocks gathered around the kernel
+(``ops.dics_update``), as in the factor kernel.
 
 Two reference quirks are replicated deliberately (see
 ``ref.dics_apply``):
@@ -16,7 +22,7 @@ Two reference quirks are replicated deliberately (see
     add both touch it), matching the reference scatter pair.
 
 Parity against the oracle is pinned by ``tests/test_kernel_parity.py``
-in interpret mode.
+in TPU interpret mode.
 """
 
 from __future__ import annotations
@@ -26,113 +32,153 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["dics_update_kernel", "dics_update_pallas"]
+from repro.kernels.bookkeeping import NEW_I, NEW_U, VALID
+
+__all__ = ["dics_update_kernel", "dics_update_pallas", "col_chunk"]
 
 
-def dics_update_kernel(
-    evu_ref, evi_ref, us_ref, is_ref,
-    co_in, cnt_in, rt_in, uid_in, iid_in, ufq_in, ifq_in, uts_in, its_in,
-    clk_in,
-    co, cnt, rt, uid, iid, ufq, ifq, uts, its, clk,
-):
-    e = pl.program_id(0)
+def col_chunk(i_cap: int) -> int:
+    """Rows per DMA of a 128-lane column tile (bounded VMEM, divides I)."""
+    best = 8
+    for c in range(8, min(i_cap, 4096) + 1, 8):
+        if i_cap % c == 0:
+            best = c
+    return best
 
-    @pl.when(e == 0)
-    def _init():
-        co[...] = co_in[...]
-        cnt[...] = cnt_in[...]
-        rt[...] = rt_in[...]
-        uid[...] = uid_in[...]
-        iid[...] = iid_in[...]
-        ufq[...] = ufq_in[...]
-        ifq[...] = ifq_in[...]
-        uts[...] = uts_in[...]
-        its[...] = its_in[...]
-        clk[...] = clk_in[...]
 
-    u_id = evu_ref[e]
-    i_id = evi_ref[e]
-    us = us_ref[e]
-    is_ = is_ref[e]
-    new_u = (uid[pl.ds(us, 1)] != u_id)[0]
-    new_i = (iid[pl.ds(is_, 1)] != i_id)[0]
+def dics_update_kernel(ua_ref, is_ref, fl_ref, rc_in, co_in, rc, co,
+                       row_buf, col_buf, add_buf, sem, *, chunk: int):
+    w = pl.program_id(0)
+    i_cap = co.shape[1]
+    rc[...] = rc_in[...]
+    lanes = jax.lax.broadcasted_iota(jnp.int32, (1, i_cap), 1)
 
-    # Eviction clears — NOT gated on event validity, by reference
-    # contract (the scan worker's lax.cond runs for padding events too).
-    r_row = rt[pl.ds(us, 1), :]
-    rt[pl.ds(us, 1), :] = jnp.where(new_u, jnp.zeros_like(r_row), r_row)
-    r_col = rt[:, pl.ds(is_, 1)]
-    rt[:, pl.ds(is_, 1)] = jnp.where(new_i, jnp.zeros_like(r_col), r_col)
-    co_row = co[pl.ds(is_, 1), :]
-    co[pl.ds(is_, 1), :] = jnp.where(new_i, jnp.zeros_like(co_row), co_row)
-    co_col = co[:, pl.ds(is_, 1)]
-    co[:, pl.ds(is_, 1)] = jnp.where(new_i, jnp.zeros_like(co_col), co_col)
-    c_v = cnt[pl.ds(is_, 1)]
-    cnt[pl.ds(is_, 1)] = jnp.where(new_i, jnp.zeros_like(c_v), c_v)
+    def event(e, carry):
+        fl = fl_ref[w, e]
+        a = ua_ref[w, e]
+        s = is_ref[w, e]
+        valid = (fl & VALID) != 0
+        new_u = (fl & NEW_U) != 0
+        new_i = (fl & NEW_I) != 0
 
-    @pl.when(u_id >= 0)
-    def _event():
-        # Rating history read AFTER the clears (it must see the evicted
-        # column as zero), BEFORE rated[u, i] is set below.
-        hist = rt[pl.ds(us, 1), :].astype(co_in.dtype)
-        co[pl.ds(is_, 1), :] = co[pl.ds(is_, 1), :] + hist
-        # Column add reads the row-updated matrix, so the diagonal picks
-        # up hist[i] twice — reference behavior.
-        co[:, pl.ds(is_, 1)] = co[:, pl.ds(is_, 1)] + hist.reshape(-1, 1)
-        cnt[pl.ds(is_, 1)] = cnt[pl.ds(is_, 1)] + 1.0
+        # Eviction clears of the rated rows (unguarded, reference order).
+        @pl.when(new_u)
+        def _():
+            rc[pl.ds(a, 1), :] = jnp.zeros((1, i_cap), rc.dtype)
 
-        ufq_v = ufq[pl.ds(us, 1)]
-        ufq[pl.ds(us, 1)] = jnp.where(new_u, 1, ufq_v + 1)
-        ifq_v = ifq[pl.ds(is_, 1)]
-        ifq[pl.ds(is_, 1)] = jnp.where(new_i, 1, ifq_v + 1)
-        uid[pl.ds(us, 1)] = jnp.expand_dims(u_id, 0)
-        iid[pl.ds(is_, 1)] = jnp.expand_dims(i_id, 0)
-        c = clk[pl.ds(0, 1)] + 1
-        uts[pl.ds(us, 1)] = c
-        its[pl.ds(is_, 1)] = c
-        clk[pl.ds(0, 1)] = c
+        @pl.when(new_i)
+        def _():
+            rc[...] = jnp.where(lanes == s, 0.0, rc[...])
 
-        row = rt[pl.ds(us, 1), :]
-        iota = jax.lax.broadcasted_iota(jnp.int32, row.shape, 1)
-        rt[pl.ds(us, 1), :] = jnp.where(iota == is_, 1, row).astype(
-            rt_in.dtype)
+        hist = rc[pl.ds(a, 1), :]          # read after clears, before set
+
+        @pl.when(valid)
+        def _():
+            rc[pl.ds(a, 1), :] = jnp.where(lanes == s, 1.0, hist)
+
+        @pl.when(valid | new_i)
+        def _():
+            add = jnp.where(valid, hist, 0.0)
+            # Row s: clear on eviction, then add the history.
+            r8 = pl.multiple_of((s // 8) * 8, 8)
+            cp = pltpu.make_async_copy(
+                co.at[w, pl.ds(r8, 8), :], row_buf, sem)
+            cp.start()
+            cp.wait()
+            rows = jax.lax.broadcasted_iota(jnp.int32, row_buf.shape, 0)
+            tile = row_buf[...]
+            mine = rows == s - r8
+            tile = jnp.where(mine & new_i, 0.0, tile)
+            row_buf[...] = jnp.where(mine, tile + add, tile)
+            cp = pltpu.make_async_copy(
+                row_buf, co.at[w, pl.ds(r8, 8), :], sem)
+            cp.start()
+            cp.wait()
+
+            # Column s: the history laid out along sublanes in lane
+            # (s - lb) of a 128-lane tile, via a transposed one-hot
+            # product (exact: every operand is 0/1).
+            lb = pl.multiple_of((s // 128) * 128, 128)
+            hist8 = jnp.where(
+                jax.lax.broadcasted_iota(jnp.int32, (8, i_cap), 0) == 0,
+                add, 0.0)
+            lane_hot = jnp.where(
+                (jax.lax.broadcasted_iota(jnp.int32, (8, 128), 0) == 0)
+                & (jax.lax.broadcasted_iota(jnp.int32, (8, 128), 1)
+                   == s - lb), 1.0, 0.0)
+            add_buf[...] = jax.lax.dot_general(
+                hist8, lane_hot, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            col_lane = jax.lax.broadcasted_iota(
+                jnp.int32, col_buf.shape, 1) == s - lb
+
+            def chunk_body(c, carry):
+                c0 = pl.multiple_of(c * chunk, chunk)
+                src = co.at[w, pl.ds(c0, chunk), pl.ds(lb, 128)]
+                cp = pltpu.make_async_copy(src, col_buf, sem)
+                cp.start()
+                cp.wait()
+                rows = c0 + jax.lax.broadcasted_iota(
+                    jnp.int32, col_buf.shape, 0)
+                # Row s was cleared above, so the column clear skips it
+                # and the diagonal keeps the row's add (double count).
+                t = col_buf[...]
+                t = jnp.where(col_lane & new_i & (rows != s), 0.0, t)
+                col_buf[...] = t + add_buf[pl.ds(c0, chunk), :]
+                cp = pltpu.make_async_copy(col_buf, src, sem)
+                cp.start()
+                cp.wait()
+                return carry
+
+            jax.lax.fori_loop(0, i_cap // chunk, chunk_body, 0)
+
+        return carry
+
+    jax.lax.fori_loop(0, fl_ref.shape[1], event, 0)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def dics_update_pallas(co, item_cnt, rated_i8, tabs, events, *,
-                       interpret: bool = False):
-    """See ``ref.dics_apply``; rated is int8 here (TPU-friendly mask).
+def dics_update_pallas(ua, i_slot, flags, rated_rows, co, *,
+                       interpret=False):
+    """Apply one bucket per worker: ``co`` in place, compact rated rows.
 
-    ``tabs`` is the flattened ``Tables`` tuple with ``clock`` as an
-    i32[1] array; ``events = (ev_u, ev_i, u_slots, i_slots)``. Returns
-    ``(co, item_cnt, rated_i8, tabs)``.
+    Leading worker axis ``W`` everywhere: ``ua, i_slot, flags``
+    i32[W, E] (scalar-prefetched compact user row, item slot,
+    ``bookkeeping`` flag bits); ``rated_rows`` f32[W, Ru, I] (0/1); ``co``
+    f32[W, I, I] with ``I % 128 == 0``. Returns ``(rated_rows, co)``,
+    aliased to the inputs.
     """
-    uid, iid, ufq, ifq, uts, its, clk = tabs
-    ev_u, ev_i, u_slots, i_slots = events
-    n_events = ev_u.shape[0]
-    vmem_bytes = (
-        4 * (co.size + item_cnt.size) + rated_i8.size
-        + 4 * (uid.size + iid.size + ufq.size + ifq.size + uts.size
-               + its.size)
-    )
-    assert vmem_bytes <= 12 * 2**20, f"state exceeds VMEM budget: {vmem_bytes}"
-
-    full = lambda x: pl.BlockSpec(  # noqa: E731 — whole-array residency
-        x.shape, (lambda e: (0,) * x.ndim))
-    ins = [
-        ev_u.astype(jnp.int32), ev_i.astype(jnp.int32),
-        u_slots.astype(jnp.int32), i_slots.astype(jnp.int32),
-        co, item_cnt, rated_i8,
-        uid, iid, ufq, ifq, uts, its, clk,
-    ]
-    outs = [co, item_cnt, rated_i8, uid, iid, ufq, ifq, uts, its, clk]
-    result = pl.pallas_call(
-        dics_update_kernel,
-        grid=(n_events,),
-        in_specs=[full(x) for x in ins],
-        out_specs=[full(x) for x in outs],
-        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype) for x in outs],
+    w, _, i_cap = co.shape
+    assert i_cap % 128 == 0, i_cap
+    chunk = col_chunk(i_cap)
+    rows_spec = pl.BlockSpec((None,) + rated_rows.shape[1:],
+                             lambda wi, *_: (wi, 0, 0))
+    any_spec = pl.BlockSpec(memory_space=pl.ANY)
+    scalars = (ua, i_slot, flags)
+    n_s = len(scalars)
+    vmem = (4 * rated_rows[0].size * 4 + 4 * (8 * i_cap + chunk * 128)
+            + 4 * i_cap * 128)
+    return pl.pallas_call(
+        functools.partial(dics_update_kernel, chunk=chunk),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=n_s, grid=(w,),
+            in_specs=[rows_spec, any_spec],
+            out_specs=[rows_spec, any_spec],
+            scratch_shapes=[
+                pltpu.VMEM((8, i_cap), jnp.float32),
+                pltpu.VMEM((chunk, 128), jnp.float32),
+                pltpu.VMEM((i_cap, 128), jnp.float32),
+                pltpu.SemaphoreType.DMA(()),
+            ]),
+        out_shape=[jax.ShapeDtypeStruct(rated_rows.shape, rated_rows.dtype),
+                   jax.ShapeDtypeStruct(co.shape, co.dtype)],
+        input_output_aliases={n_s: 0, n_s + 1: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=int(min(max(32 * 2**20, 2 * vmem),
+                                     100 * 2**20))),
         interpret=interpret,
-    )(*ins)
-    return result[0], result[1], result[2], tuple(result[3:])
+        name="dics_update",
+    )(*scalars, rated_rows, co)

@@ -1,30 +1,27 @@
-"""Pallas TPU kernel: COMPLETE streaming factor-model micro-batch update.
+"""Pallas TPU kernel: streaming factor-model micro-batch update.
 
-``kernels/isgd.py`` fuses only the factor tables; the reference workers
-also maintain id/freshness/frequency tables and the rated bitmap per
-event, so a fast path built on the factors-only kernel has to
-approximate the bookkeeping batched (last-writer-wins) and loses bit
-parity under slot collisions. This kernel closes that gap: one
-sequential grid step per event applies the WHOLE worker state
-transition — gather, (pairwise) SGD update, collision eviction, rated
-row/column maintenance, freq/ts/clock bookkeeping — with every table
-pinned in VMEM for the duration of the micro-batch (same
-whole-table-resident layout as ``isgd.py``; HBM traffic is one state
-round-trip per micro-batch, not per event).
+One kernel applies a bucket of events to the rows they touch, in event
+order, for every worker of the grid (grid axis = worker). The worker's
+tables stay in HBM; around the kernel, ``ops.factor_update`` gathers the
+touched rows into compact blocks (one row per distinct slot — events
+that share a slot share the row, so their updates chain exactly as in
+the sequential reference) and scatters them back in place afterwards.
+So the per-call traffic is O(touched rows), never the whole state.
 
-Two training rules share the body, selected by the static ``pairwise``
-flag:
+Per event the kernel reads scalar-prefetched compact indices and flags
+(``bookkeeping.plan_bucket``: validity, collision eviction of the user
+and item slot, the BPR negative's precondition) and does the gather,
+(pairwise) SGD step and rated-row maintenance:
 
   * plain ISGD (DISGD, Alg. 2): ``err = 1 - u.i`` rank-1 update;
-  * pairwise BPR: sampled-negative step on ``ln sigmoid(x_ui - x_uj)``
-    with the negative slot pre-sampled on the host side via the
-    ``fold_in(key, clock, u_id)`` replay contract (``algos/bpr.py``) and
-    validated against the LIVE tables in-kernel (``neg_ok``), so the
-    skip rule sees exactly the state the reference sees.
+  * pairwise BPR: sampled-negative step on ``ln sigmoid(x_ui - x_uj)``,
+    skipped unless the negative slot is usable, which includes "not in
+    the user's live rated row" — read here from the compact row.
 
-Semantics replicate ``ref.factor_apply`` (the jnp oracle, itself exact
-against the reference scan workers); parity is pinned by
-``tests/test_kernel_parity.py`` in interpret mode.
+An evicted item's rated column is cleared in the compact rows here and
+in the rest of the table by ``ops.factor_update`` (one column scatter
+per eviction). Semantics replicate ``ref.factor_apply`` exactly; parity
+is pinned by ``tests/test_kernel_parity.py`` in interpret mode.
 """
 
 from __future__ import annotations
@@ -34,141 +31,127 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.bookkeeping import NEG_OK, NEW_I, NEW_U, VALID
 
 __all__ = ["factor_update_kernel", "factor_update_pallas"]
 
 
-def factor_update_kernel(
-    evu_ref, evi_ref, us_ref, is_ref, js_ref, iu_ref, ii_ref,
-    uv_in, iv_in, rt_in, uid_in, iid_in, ufq_in, ifq_in, uts_in, its_in,
-    clk_in,
-    uv, iv, rt, uid, iid, ufq, ifq, uts, its, clk,
-    *, eta: float, lam: float, pairwise: bool,
-):
-    e = pl.program_id(0)
+def factor_update_kernel(ua_ref, ia_ref, ja_ref, is_ref, js_ref, fl_ref,
+                         iu_ref, ii_ref, uc_in, ic_in, rc_in,
+                         uc, ic, rc, *, eta: float, lam: float,
+                         pairwise: bool):
+    w = pl.program_id(0)
+    uc[...] = uc_in[...]
+    ic[...] = ic_in[...]
+    rc[...] = rc_in[...]
+    lanes = jax.lax.broadcasted_iota(jnp.int32, (1, rc.shape[1]), 1)
 
-    @pl.when(e == 0)
-    def _init():
-        # First grid step: bring the whole state into the output buffers.
-        uv[...] = uv_in[...]
-        iv[...] = iv_in[...]
-        rt[...] = rt_in[...]
-        uid[...] = uid_in[...]
-        iid[...] = iid_in[...]
-        ufq[...] = ufq_in[...]
-        ifq[...] = ifq_in[...]
-        uts[...] = uts_in[...]
-        its[...] = its_in[...]
-        clk[...] = clk_in[...]
+    def event(e, carry):
+        fl = fl_ref[w, e]
 
-    u_id = evu_ref[e]
-    i_id = evi_ref[e]
+        @pl.when((fl & VALID) != 0)
+        def _():
+            a = ua_ref[w, e]
+            ia = ia_ref[w, e]
+            s = is_ref[w, e]
+            new_u = (fl & NEW_U) != 0
+            new_i = (fl & NEW_I) != 0
 
-    @pl.when(u_id >= 0)
-    def _event():
-        us = us_ref[e]
-        is_ = is_ref[e]
-        new_u = (uid[pl.ds(us, 1)] != u_id)[0]
-        new_i = (iid[pl.ds(is_, 1)] != i_id)[0]
-        u_vec = jnp.where(new_u, iu_ref[pl.ds(e, 1), :], uv[pl.ds(us, 1), :])
-        i_vec = jnp.where(new_i, ii_ref[pl.ds(e, 1), :], iv[pl.ds(is_, 1), :])
+            # Eviction of the item slot clears its rated column in every
+            # compact row before the user's row is read.
+            @pl.when(new_i)
+            def _():
+                rc[...] = jnp.where(lanes == s, 0.0, rc[...])
 
-        # Collision eviction on the rated bitmap: clear the evicted
-        # item's column first, then read the user's row (so the row sees
-        # the cleared entry) — same order as the reference's scatters.
-        col = rt[:, pl.ds(is_, 1)]
-        rt[:, pl.ds(is_, 1)] = jnp.where(new_i, jnp.zeros_like(col), col)
-        row = rt[pl.ds(us, 1), :]
-        row = jnp.where(new_u, jnp.zeros_like(row), row)
-        iota = jax.lax.broadcasted_iota(jnp.int32, row.shape, 1)
+            row = rc[pl.ds(a, 1), :]
+            row = jnp.where(new_u, 0.0, row)
+            u_vec = jnp.where(new_u, iu_ref[pl.ds(e, 1), :],
+                              uc[pl.ds(a, 1), :])
+            i_vec = jnp.where(new_i, ii_ref[pl.ds(e, 1), :],
+                              ic[pl.ds(ia, 1), :])
+            if pairwise:
+                ja = ja_ref[w, e]
+                js = js_ref[w, e]
+                row_j = jnp.max(jnp.where(lanes == js, row, 0.0))
+                neg_ok = ((fl & NEG_OK) != 0) & (row_j == 0.0)
+                j_vec = ic[pl.ds(ja, 1), :]
+                x = jnp.sum(u_vec * i_vec) - jnp.sum(u_vec * j_vec)
+                sg = jax.nn.sigmoid(-x)
+                u_new = jnp.where(
+                    neg_ok, u_vec + eta * (sg * (i_vec - j_vec) - lam * u_vec),
+                    u_vec)
+                i_new = jnp.where(
+                    neg_ok, i_vec + eta * (sg * u_vec - lam * i_vec), i_vec)
 
-        if pairwise:
-            js = js_ref[e]
-            neg_id = (iid[pl.ds(js, 1)])[0]
-            row_j = jnp.sum(jnp.where(iota == js, row, 0))
-            neg_ok = ((neg_id >= 0) & (neg_id != i_id) & (js != is_)
-                      & (row_j == 0))
-            j_vec = iv[pl.ds(js, 1), :]
-            x = jnp.sum(u_vec * i_vec) - jnp.sum(u_vec * j_vec)
-            s = jax.nn.sigmoid(-x)
-            u_new = jnp.where(
-                neg_ok, u_vec + eta * (s * (i_vec - j_vec) - lam * u_vec),
-                u_vec)
-            i_new = jnp.where(
-                neg_ok, i_vec + eta * (s * u_vec - lam * i_vec), i_vec)
-            j_new = jnp.where(
-                neg_ok, j_vec + eta * (-s * u_vec - lam * j_vec), j_vec)
-            # Write j before i: when the sampled slot is unusable and
-            # aliases i_slot, i's update must win (the reference drops
-            # the j write entirely; here the no-op write-back of j_vec
-            # would otherwise clobber it).
-            iv[pl.ds(js, 1), :] = j_new
-        else:
-            err = 1.0 - jnp.sum(u_vec * i_vec)
-            u_new = u_vec + eta * (err * i_vec - lam * u_vec)
-            i_new = i_vec + eta * (err * u_vec - lam * i_vec)
+                # A usable negative never shares the positive's slot.
+                @pl.when(neg_ok)
+                def _():
+                    ic[pl.ds(ja, 1), :] = (
+                        j_vec + eta * (-sg * u_vec - lam * j_vec))
+            else:
+                err = 1.0 - jnp.sum(u_vec * i_vec)
+                u_new = u_vec + eta * (err * i_vec - lam * u_vec)
+                i_new = i_vec + eta * (err * u_vec - lam * i_vec)
+            uc[pl.ds(a, 1), :] = u_new
+            ic[pl.ds(ia, 1), :] = i_new
+            rc[pl.ds(a, 1), :] = jnp.where(lanes == s, 1.0, row)
 
-        uv[pl.ds(us, 1), :] = u_new
-        iv[pl.ds(is_, 1), :] = i_new
-        rt[pl.ds(us, 1), :] = jnp.where(iota == is_, 1, row).astype(rt_in.dtype)
+        return carry
 
-        # Bookkeeping tables (freq reads must precede the id writes only
-        # in the sense of the reference: both read pre-write values).
-        ufq_v = ufq[pl.ds(us, 1)]
-        ufq[pl.ds(us, 1)] = jnp.where(new_u, 1, ufq_v + 1)
-        ifq_v = ifq[pl.ds(is_, 1)]
-        ifq[pl.ds(is_, 1)] = jnp.where(new_i, 1, ifq_v + 1)
-        uid[pl.ds(us, 1)] = jnp.expand_dims(u_id, 0)
-        iid[pl.ds(is_, 1)] = jnp.expand_dims(i_id, 0)
-        c = clk[pl.ds(0, 1)] + 1
-        uts[pl.ds(us, 1)] = c
-        its[pl.ds(is_, 1)] = c
-        clk[pl.ds(0, 1)] = c
+    jax.lax.fori_loop(0, fl_ref.shape[1], event, 0)
+
+
+def _vmem_limit(*blocks) -> int:
+    # Each block is double-buffered on the way in and out.
+    need = 4 * sum(b.size * b.dtype.itemsize for b in blocks)
+    return int(min(max(32 * 2**20, 2 * need), 100 * 2**20))
 
 
 @functools.partial(
     jax.jit, static_argnames=("eta", "lam", "pairwise", "interpret"))
-def factor_update_pallas(
-    user_vecs, item_vecs, rated_i8, tabs, events, *, eta: float, lam: float,
-    pairwise: bool, interpret: bool = False,
-):
-    """See ``ref.factor_apply``; rated is int8 here (TPU-friendly mask).
+def factor_update_pallas(ua, ia, ja, i_slot, j_slot, flags, init_u, init_i,
+                         u_rows, i_rows, rated_rows, *, eta: float,
+                         lam: float, pairwise: bool, interpret=False):
+    """Apply one bucket per worker to compact touched-row blocks.
 
-    ``tabs`` is the flattened ``Tables`` tuple with ``clock`` as an
-    i32[1] array; ``events = (ev_u, ev_i, u_slots, i_slots, j_slots,
-    init_u, init_i)`` with ``j_slots`` always materialized (ignored when
-    ``pairwise=False``). Returns ``(user_vecs, item_vecs, rated_i8,
-    tabs)``.
+    All arrays carry a leading worker axis ``W``:
+
+      ua, ia, ja, i_slot, j_slot, flags: i32[W, E] scalar-prefetched
+        per-event compact user row, compact item rows of the positive
+        and of the negative, item slot and negative slot (rated-row
+        lanes), and ``bookkeeping`` flag bits;
+      init_u / init_i: f32[W, E, k] replica-consistent init vectors;
+      u_rows / i_rows: f32[W, Ru, k] / f32[W, Ri, k] compact factor rows;
+      rated_rows: f32[W, Ru, I] compact rated rows (0/1).
+
+    Returns the updated ``(u_rows, i_rows, rated_rows)``, aliased to the
+    inputs.
     """
-    uid, iid, ufq, ifq, uts, its, clk = tabs
-    ev_u, ev_i, u_slots, i_slots, j_slots, init_u, init_i = events
-    n_events = ev_u.shape[0]
-    vmem_bytes = (
-        4 * (user_vecs.size + item_vecs.size + init_u.size + init_i.size)
-        + rated_i8.size
-        + 4 * (uid.size + iid.size + ufq.size + ifq.size + uts.size
-               + its.size)
-    )
-    assert vmem_bytes <= 12 * 2**20, f"state exceeds VMEM budget: {vmem_bytes}"
+    w = u_rows.shape[0]
+    kernel = functools.partial(factor_update_kernel, eta=eta, lam=lam,
+                               pairwise=pairwise)
 
-    kernel = functools.partial(
-        factor_update_kernel, eta=eta, lam=lam, pairwise=pairwise)
-    full = lambda x: pl.BlockSpec(  # noqa: E731 — whole-array residency
-        x.shape, (lambda e: (0,) * x.ndim))
-    ins = [
-        ev_u.astype(jnp.int32), ev_i.astype(jnp.int32),
-        u_slots.astype(jnp.int32), i_slots.astype(jnp.int32),
-        j_slots.astype(jnp.int32), init_u, init_i,
-        user_vecs, item_vecs, rated_i8,
-        uid, iid, ufq, ifq, uts, its, clk,
-    ]
-    outs = [user_vecs, item_vecs, rated_i8, uid, iid, ufq, ifq, uts, its, clk]
-    result = pl.pallas_call(
+    def blk(x):
+        return pl.BlockSpec((None,) + x.shape[1:],
+                            lambda wi, *_: (wi,) + (0,) * (x.ndim - 1))
+
+    ins = [init_u, init_i, u_rows, i_rows, rated_rows]
+    outs = [u_rows, i_rows, rated_rows]
+    scalars = (ua, ia, ja, i_slot, j_slot, flags)
+    n_s = len(scalars)
+    return pl.pallas_call(
         kernel,
-        grid=(n_events,),
-        in_specs=[full(x) for x in ins],
-        out_specs=[full(x) for x in outs],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=n_s, grid=(w,),
+            in_specs=[blk(x) for x in ins],
+            out_specs=[blk(x) for x in outs]),
         out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype) for x in outs],
+        input_output_aliases={n_s + 2: 0, n_s + 3: 1, n_s + 4: 2},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_vmem_limit(*ins)),
         interpret=interpret,
-    )(*ins)
-    return result[0], result[1], result[2], tuple(result[3:])
+        name="factor_update",
+    )(*scalars, *ins)

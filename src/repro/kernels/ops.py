@@ -6,27 +6,29 @@ hardware-aligned shapes first); off TPU the op returns its jnp oracle
 interpret-mode kernel, which emulates the grid step-by-step and is two
 orders of magnitude slower than the oracle. Pass ``interpret=True`` to
 force the interpret-mode kernel body anywhere (the parity tests do, so
-the kernel semantics stay validated on every platform), or
+the kernel semantics stay validated on every platform; the update
+kernels run in TPU interpret mode, which emulates their DMAs), or
 ``interpret=False`` to force a real kernel launch.
 """
 
 from __future__ import annotations
 
-from functools import partial
+import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import custom_batching
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import ref
+from repro.kernels import bookkeeping, ref
 from repro.kernels.dics_update import dics_update_pallas
 from repro.kernels.factor_update import factor_update_pallas
-from repro.kernels.isgd import isgd_update_pallas
 from repro.kernels.scoring import masked_scores_pallas
 from repro.kernels.swa_attention import swa_attention_pallas
-from repro.kernels.topn import dics_topn_pallas, fused_topn_pallas
+from repro.kernels.topn import QUERY_BLOCK, dics_topn_pallas, fused_topn_pallas
 
-__all__ = ["on_tpu", "masked_scores", "isgd_update", "factor_update",
+__all__ = ["on_tpu", "masked_scores", "clear_columns", "factor_update",
            "dics_update", "fused_topn", "dics_topn", "swa_attention",
            "topn_select", "topn_merge"]
 
@@ -68,35 +70,99 @@ def masked_scores(u_vecs, item_vecs, mask, *, block_b: int = 128,
     return out[:b, :i]
 
 
-def isgd_update(user_tab, item_tab, u_slots, i_slots, valid, *, eta: float,
-                lam: float, interpret: bool | None = None):
-    """Streaming ISGD micro-batch update; returns updated tables."""
-    if interpret is None:
-        if not on_tpu():
-            return ref.isgd_apply(
-                user_tab, item_tab, u_slots, i_slots, valid, eta=eta, lam=lam)
-        interpret = False
-    k = user_tab.shape[1]
-    if k % 128 != 0:
-        # Lane-pad the factor dim; zero columns are invariant under the
-        # update (err uses the dot over true lanes only since pads are 0).
-        user_p = _pad_to(user_tab, 1, 128)
-        item_p = _pad_to(item_tab, 1, 128)
-        u_out, i_out = isgd_update_pallas(
-            user_p, item_p, u_slots, i_slots, valid, eta=eta, lam=lam,
-            interpret=interpret,
-        )
-        return u_out[:, :k], i_out[:, :k]
-    return isgd_update_pallas(
-        user_tab, item_tab, u_slots, i_slots, valid, eta=eta, lam=lam,
-        interpret=interpret,
-    )
+def _interpret_mode(interpret):
+    """``interpret=True`` runs the kernel body in TPU interpret mode, which
+    also emulates its DMAs and semaphores."""
+    return pltpu.InterpretParams() if interpret is True else interpret
 
 
-def _split_tabs(tabs):
-    """Flattened Tables tuple -> (bookkeeping arrays, clock as i32[1])."""
-    uid, iid, ufq, ifq, uts, its, clock = tabs
-    return (uid, iid, ufq, ifq, uts, its), jnp.asarray(clock).reshape(1)
+def _worker_batched(kernel_fn, n_out: int):
+    """Per-worker view of a function written for a leading worker axis.
+
+    Called unbatched it runs on a one-worker axis; under ``vmap`` (the
+    engine maps the worker step over the S&R grid) the batching rule
+    hands the whole grid to ONE call — for the kernels, one launch whose
+    grid axis is the worker. Pallas' generic batching rule would instead
+    loop over workers and copy every HBM-resident table per call.
+    """
+
+    @custom_batching.custom_vmap
+    def one(*args):
+        outs = kernel_fn(*(a[None] for a in args))
+        return tuple(o[0] for o in outs)
+
+    @one.def_vmap
+    def _rule(axis_size, in_batched, *args):
+        args = [a if b else jnp.broadcast_to(a, (axis_size,) + a.shape)
+                for a, b in zip(args, in_batched)]
+        return tuple(kernel_fn(*args)), (True,) * n_out
+
+    return one
+
+
+@functools.lru_cache(maxsize=64)
+def _factor_kernel(eta: float, lam: float, pairwise: bool, interpret):
+    return _worker_batched(functools.partial(
+        factor_update_pallas, eta=eta, lam=lam, pairwise=pairwise,
+        interpret=_interpret_mode(interpret)), 3)
+
+
+@functools.lru_cache(maxsize=8)
+def _dics_kernel(interpret):
+    return _worker_batched(functools.partial(
+        dics_update_pallas, interpret=_interpret_mode(interpret)), 2)
+
+
+def _compact_rows(table, slots):
+    """Gather the rows ``slots`` touch, one per distinct slot.
+
+    Returns ``(rows, first, dest)``: ``rows[p]`` is ``table[slots[p]]``;
+    ``first[p]`` is the position of the first event holding the same
+    slot (every event of a slot works on that one row); ``dest`` is the
+    scatter target for writing the rows back (the table size — dropped —
+    for repeats).
+    """
+    first = bookkeeping.first_index(slots)
+    own = first == jnp.arange(slots.shape[0], dtype=jnp.int32)
+    return table[slots], first, jnp.where(own, slots, table.shape[0])
+
+
+def _clear_columns_batched(table, cols, clear):
+    """Zero column ``cols[w, e]`` of worker ``w``'s ``table`` [W, U, I]
+    wherever ``clear[w, e]``, in event order. Each clear is one in-place
+    column update: a column scatter next to the tables' row accesses
+    makes XLA transpose the whole table around it on the TPU."""
+    n_w, n_e = clear.shape
+    zeros = jnp.zeros((1, table.shape[1], 1), table.dtype)
+
+    def clear_one(t, r):
+        w, e = t // n_e, t % n_e
+        return jax.lax.cond(
+            clear[w, e],
+            lambda r: jax.lax.dynamic_update_slice(r, zeros,
+                                                   (w, 0, cols[w, e])),
+            lambda r: r, r)
+
+    return (jax.lax.fori_loop(0, n_w * n_e, clear_one, table),)
+
+
+clear_columns = _worker_batched(_clear_columns_batched, 1)
+
+
+def _write_rated_batched(rated, cols, clear, dest, rows):
+    """Write a bucket's rated-table changes back, for ``W`` workers.
+
+    ``rated`` bool[W, U, I]; ``cols``/``clear`` [W, E] name the evicted
+    item slots whose columns are zeroed, and ``dest``/``rows`` [W, E] /
+    [W, E, I] the compact user rows to write back (which already carry
+    those clears).
+    """
+    (rated,) = _clear_columns_batched(rated, cols, clear)
+    w_idx = jnp.broadcast_to(jnp.arange(rated.shape[0])[:, None], dest.shape)
+    return (rated.at[w_idx, dest].set(rows, mode="drop"),)
+
+
+_write_rated = _worker_batched(_write_rated_batched, 1)
 
 
 def factor_update(user_vecs, item_vecs, rated, tabs, events, *, eta: float,
@@ -104,8 +170,11 @@ def factor_update(user_vecs, item_vecs, rated, tabs, events, *, eta: float,
     """Complete factor-model micro-batch update (vectors + bookkeeping +
     rated bitmap), plain ISGD or pairwise BPR by the shape of ``events``.
 
-    See ``ref.factor_apply`` for the full contract; this entry point adds
-    the kernel dispatch and TPU shape alignment. Returns
+    See ``ref.factor_apply`` for the full contract. On the kernel path
+    the bookkeeping tables and per-event flags come from
+    ``bookkeeping.plan_bucket``, the touched factor and rated rows are
+    gathered into compact blocks, the kernel applies the events to them
+    in order, and the rows are scattered back in place. Returns
     ``(user_vecs, item_vecs, rated, tabs)``.
     """
     if interpret is None:
@@ -116,22 +185,24 @@ def factor_update(user_vecs, item_vecs, rated, tabs, events, *, eta: float,
     ev_u, ev_i, u_slots, i_slots, j_slots, init_u, init_i = events
     pairwise = j_slots is not None
     if not pairwise:
-        j_slots = jnp.zeros_like(i_slots)
-    (uid, iid, ufq, ifq, uts, its), clk = _split_tabs(tabs)
-    k = user_vecs.shape[1]
-    uv = _pad_to(user_vecs, 1, 128)
-    iv = _pad_to(item_vecs, 1, 128)
-    ini_u = _pad_to(init_u, 1, 128)
-    ini_i = _pad_to(init_i, 1, 128)
-    uv, iv, rated_i8, out_tabs = factor_update_pallas(
-        uv, iv, rated.astype(jnp.int8),
-        (uid, iid, ufq, ifq, uts, its, clk),
-        (ev_u, ev_i, u_slots, i_slots, j_slots, ini_u, ini_i),
-        eta=eta, lam=lam, pairwise=pairwise, interpret=interpret,
-    )
-    uid, iid, ufq, ifq, uts, its, clk = out_tabs
-    return (uv[:, :k], iv[:, :k], rated_i8.astype(bool),
-            (uid, iid, ufq, ifq, uts, its, clk.reshape(())))
+        j_slots = i_slots
+    plan = bookkeeping.plan_bucket(tuple(tabs), ev_u, ev_i, u_slots, i_slots,
+                                   j_slots if pairwise else None)
+    e = ev_u.shape[0]
+    u_rows, ua, u_dest = _compact_rows(user_vecs, u_slots)
+    cat = jnp.concatenate([i_slots, j_slots])
+    i_rows, cat_first, i_dest = _compact_rows(item_vecs, cat)
+    r_rows = rated[u_slots].astype(jnp.float32)
+    scalars = (ua, cat_first[:e], cat_first[e:], i_slots.astype(jnp.int32),
+               j_slots.astype(jnp.int32), plan.flags)
+    kernel = _factor_kernel(eta, lam, pairwise, interpret)
+    u_rows, i_rows, r_rows = kernel(*scalars, init_u, init_i, u_rows, i_rows,
+                                    r_rows)
+    (rated,) = _write_rated(rated, i_slots, (ev_u >= 0) & plan.new_i, u_dest,
+                            r_rows != 0)
+    user_vecs = user_vecs.at[u_dest].set(u_rows, mode="drop")
+    item_vecs = item_vecs.at[i_dest].set(i_rows, mode="drop")
+    return user_vecs, item_vecs, rated, plan.tables
 
 
 def dics_update(co, item_cnt, rated, tabs, events, *,
@@ -139,20 +210,29 @@ def dics_update(co, item_cnt, rated, tabs, events, *,
     """DICS co-occurrence micro-batch update (Eq. 6 statistics +
     bookkeeping). See ``ref.dics_apply``; returns
     ``(co, item_cnt, rated, tabs)``.
+
+    On the kernel path ``co`` is updated in place by
+    ``kernels/dics_update.py`` (its item width is padded to a multiple
+    of 128 only when it is not one already), the support counts and
+    bookkeeping come from ``bookkeeping.plan_bucket``, and the touched
+    rated rows travel as compact blocks like in :func:`factor_update`.
     """
     if interpret is None:
         if not on_tpu():
             return ref.dics_apply(co, item_cnt, rated, tabs, events)
         interpret = False
-    (uid, iid, ufq, ifq, uts, its), clk = _split_tabs(tabs)
-    co, item_cnt, rated_i8, out_tabs = dics_update_pallas(
-        co, item_cnt, rated.astype(jnp.int8),
-        (uid, iid, ufq, ifq, uts, its, clk),
-        events, interpret=interpret,
-    )
-    uid, iid, ufq, ifq, uts, its, clk = out_tabs
-    return (co, item_cnt, rated_i8.astype(bool),
-            (uid, iid, ufq, ifq, uts, its, clk.reshape(())))
+    ev_u, ev_i, u_slots, i_slots = events
+    plan = bookkeeping.plan_bucket(tuple(tabs), ev_u, ev_i, u_slots, i_slots,
+                                   item_cnt=item_cnt)
+    i_cap = co.shape[0]
+    r_rows, ua, u_dest = _compact_rows(rated, u_slots)
+    r_rows = _pad_to(r_rows.astype(jnp.float32), 1, 128)
+    co_p = _pad_to(_pad_to(co, 0, 128), 1, 128)
+    scalars = (ua, i_slots.astype(jnp.int32), plan.flags)
+    r_rows, co_p = _dics_kernel(interpret)(*scalars, r_rows, co_p)
+    (rated,) = _write_rated(rated, i_slots, plan.new_i, u_dest,
+                            r_rows[:, :i_cap] != 0)
+    return co_p[:i_cap, :i_cap], plan.item_cnt, rated, plan.tables
 
 
 def fused_topn(u_vecs, item_vecs, mask, item_ids, *, top_n: int,
@@ -212,20 +292,24 @@ def dics_topn(co, item_cnt, hist, known, item_ids, *, top_n: int,
     """
     if interpret is None:
         interpret = not on_tpu()
+    b = hist.shape[0]
     i = co.shape[0]
     block_p = min(block_p, max(128, 1 << (i - 1).bit_length()))
     cop = _pad_to(_pad_to(co, 0, block_p), 1, block_p)
     cntp = _pad_to(item_cnt.reshape(1, -1), 1, block_p)
-    histp = _pad_to(hist.astype(jnp.int8), 1, block_p)
+    # Queries come in sublane-aligned blocks; padded rows are unknown
+    # users and are cut off below.
+    histp = _pad_to(_pad_to(hist.astype(jnp.int8), 1, block_p), 0,
+                    QUERY_BLOCK)
+    knownp = _pad_to(known.astype(jnp.int32).reshape(-1, 1), 0, QUERY_BLOCK)
     # Padded candidates carry cnt 0 -> zero neighbor mass -> excluded by
     # the score > 0 rule; id INT32_MAX keeps them after every real entry.
     idp = _pad_to(item_ids.reshape(1, -1), 1, block_p, value=_I32_MAX)
     out_id, out_sc = dics_topn_pallas(
-        cop, cntp, histp, known.astype(jnp.int32).reshape(-1, 1),
-        idp.astype(jnp.int32), top_n=top_n, k_nn=k_nn, block_p=block_p,
-        interpret=interpret,
+        cop, cntp, histp, knownp, idp.astype(jnp.int32), top_n=top_n,
+        k_nn=k_nn, block_p=block_p, interpret=interpret,
     )
-    return out_id, out_sc
+    return out_id[:b], out_sc[:b]
 
 
 def topn_select(scores, ids, top_n: int):

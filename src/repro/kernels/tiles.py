@@ -1,8 +1,8 @@
 """Execution-tile registry: cached autotune winners per (op, algo, backend).
 
 The kernel-level knobs that dominate single-host throughput are not the
-Pallas block shapes (the update kernels are whole-table VMEM-resident)
-but the EXECUTION tiles the engine feeds them: the micro-batch size (how
+Pallas block shapes (the update kernels walk one bucket of touched rows
+per worker) but the EXECUTION tiles the engine feeds them: the micro-batch size (how
 many events amortize one dispatch) and the per-bucket capacity factor
 (how much padding headroom each worker bucket gets before events drop).
 ``benchmarks/bench_kernels.py --autotune`` sweeps these per (algorithm,

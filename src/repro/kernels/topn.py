@@ -16,10 +16,11 @@ invariance tests keep pinning one deterministic list.
 
   * ``fused_topn_pallas``   — factor-model leaf (DISGD / BPR-MF):
     grid (B-tiles, I-tiles), dot_general f32 tile matmul + mask, merge.
-  * ``dics_topn_pallas``    — DICS Eq. 6/7 leaf: grid (B, cand-tiles);
-    each tile builds its slice of the similarity matrix from the co /
-    item_cnt statistics, restricts neighborhoods to the query's rated
-    history, takes the top-k_nn neighbor mass, then merges.
+  * ``dics_topn_pallas``    — DICS Eq. 6/7 leaf: grid (query blocks,
+    cand-tiles); each tile builds its slice of the similarity matrix
+    from the co / item_cnt statistics once for a block of 8 queries,
+    restricts neighborhoods to each query's rated history, takes the
+    top-k_nn neighbor mass, then merges.
 
 Merging is exact: a running top-N merged with each tile's candidates
 equals the top-N of the union, because every selection keeps the N
@@ -37,9 +38,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["fused_topn_pallas", "dics_topn_pallas"]
+__all__ = ["fused_topn_pallas", "dics_topn_pallas", "QUERY_BLOCK"]
 
 _I32_MAX = jnp.iinfo(jnp.int32).max
+# DICS leaf queries per grid step: one sublane tile, the smallest legal
+# block for the per-query rated rows.
+QUERY_BLOCK = 8
 
 
 def _merge_topn(run_sc, run_id, cand_sc, cand_id, top_n: int):
@@ -154,39 +158,54 @@ def _dics_topn_kernel(co_ref, cnt_t_ref, cnt_all_ref, hist_ref, hist_t_ref,
     co_t = co_ref[...]                       # [block_p, I] candidate rows
     cnt_p = cnt_t_ref[...]                   # [1, block_p]
     cnt_all = cnt_all_ref[...]               # [1, I]
-    hist = hist_ref[...]                     # [1, I] query's rated row
     width = co_t.shape[1]
 
-    # Eq. 6 slice: sim(p, q) = co / sqrt(cnt_p * cnt_q), 0 where
-    # unsupported, and an item is not its own neighbor (diagonal zero —
-    # here: global column index == global candidate index).
+    # Eq. 6 slice, shared by the query block: sim(p, q) = co / sqrt(cnt_p
+    # * cnt_q), 0 where unsupported, and an item is not its own neighbor
+    # (diagonal zero — here: global column index == global candidate
+    # index).
     denom = jnp.sqrt(cnt_p.reshape(-1, 1) * cnt_all)
     sim = jnp.where(denom > 0, co_t / jnp.maximum(denom, 1e-12), 0.0)
     cols = jax.lax.broadcasted_iota(jnp.int32, sim.shape, 1)
     rows = jax.lax.broadcasted_iota(jnp.int32, sim.shape, 0)
     sim = jnp.where(cols == pi * block_p + rows, 0.0, sim)
-    # Eq. 7: neighborhoods restricted to the user's rated history, then
-    # top-k_nn neighbor mass per candidate. Iterative max-extract ==
-    # top_k sum: sims are >= 0, consumed slots go to -1 and are never
-    # re-picked while any unconsumed entry remains.
-    vals = jnp.where(hist != 0, sim, 0.0)
-    acc = jnp.zeros((vals.shape[0],), jnp.float32)
-    for _ in range(k_nn):
-        m = jnp.max(vals, axis=1, keepdims=True)
-        first = jnp.min(jnp.where(vals == m, cols, width), axis=1,
-                        keepdims=True)
-        acc = acc + m[:, 0]
-        vals = jnp.where(cols == first, -1.0, vals)
+    hist_all = hist_ref[...].astype(jnp.float32)       # [block_q, I]
+    hist_t = hist_t_ref[...].astype(jnp.float32)       # [block_q, block_p]
+    known = known_ref[...].astype(jnp.float32)         # [block_q, 1]
+    live = ids_t_ref[...] >= 0                         # [1, block_p]
 
-    # Candidate rule, matching dics_partial_topn: live slot, unrated by
-    # this user, known user, strictly positive neighbor mass.
-    valid = ((ids_t_ref[...][0] >= 0) & (hist_t_ref[...][0] == 0)
-             & (known_ref[0, 0] != 0) & (acc > 0))
-    scores = jnp.where(valid, acc, -jnp.inf).reshape(1, -1)
-    new_sc, new_id = _merge_topn(
-        run_sc[...], run_id[...], scores, ids_t_ref[...], top_n)
-    run_sc[...] = new_sc
-    run_id[...] = new_id
+    def pick(x, q):
+        """Row ``q`` of a [block_q, n] value, as [1, n]."""
+        rows_q = jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)
+        return jnp.sum(jnp.where(rows_q == q, x, 0.0), axis=0, keepdims=True)
+
+    def query(q, carry):
+        # Eq. 7: neighborhoods restricted to the user's rated history,
+        # then top-k_nn neighbor mass per candidate. Iterative
+        # max-extract == top_k sum: sims are >= 0, consumed slots go to
+        # -1 and are never re-picked while any unconsumed entry remains.
+        vals = jnp.where(pick(hist_all, q) != 0, sim, 0.0)
+        acc = jnp.zeros((vals.shape[0],), jnp.float32)
+        for _ in range(k_nn):
+            m = jnp.max(vals, axis=1, keepdims=True)
+            first = jnp.min(jnp.where(vals == m, cols, width), axis=1,
+                            keepdims=True)
+            acc = acc + m[:, 0]
+            vals = jnp.where(cols == first, -1.0, vals)
+
+        # Candidate rule, matching dics_partial_topn: live slot, unrated
+        # by this user, known user, strictly positive neighbor mass.
+        valid = (live[0] & (pick(hist_t, q)[0] == 0)
+                 & (pick(known, q)[0, 0] != 0) & (acc > 0))
+        scores = jnp.where(valid, acc, -jnp.inf).reshape(1, -1)
+        new_sc, new_id = _merge_topn(
+            run_sc[pl.ds(q, 1), :], run_id[pl.ds(q, 1), :], scores,
+            ids_t_ref[...], top_n)
+        run_sc[pl.ds(q, 1), :] = new_sc
+        run_id[pl.ds(q, 1), :] = new_id
+        return carry
+
+    jax.lax.fori_loop(0, hist_all.shape[0], query, 0)
 
     @pl.when(pi == n_p_tiles - 1)
     def _flush():
@@ -201,10 +220,15 @@ def dics_topn_pallas(co, item_cnt_row, hist_i8, known_i32, ids_row, *,
                      interpret: bool = False):
     """DICS serve leaf: Eq. 6/7 scoring + partial top-N, one kernel.
 
+    Each grid step scores one candidate tile for a sublane-aligned block
+    of ``QUERY_BLOCK`` queries, so the similarity tile is built once per
+    block instead of once per query.
+
     Args:
       co: f32[I, I] co-rating counts (I % block_p == 0, I % 128 == 0).
       item_cnt_row: f32[1, I] item support counts.
-      hist_i8: i8[B, I] per-query rated rows (already known-masked).
+      hist_i8: i8[B, I] per-query rated rows (already known-masked;
+        B % QUERY_BLOCK == 0).
       known_i32: i32[B, 1] 1 where the query user is known.
       ids_row: i32[1, I] global item ids (padding entries -1).
 
@@ -213,33 +237,37 @@ def dics_topn_pallas(co, item_cnt_row, hist_i8, known_i32, ids_row, *,
     b = hist_i8.shape[0]
     i = co.shape[0]
     n_p_tiles = i // block_p
+    block_q = QUERY_BLOCK
     kernel = functools.partial(
         _dics_topn_kernel, top_n=top_n, k_nn=k_nn, block_p=block_p,
         n_p_tiles=n_p_tiles)
     out_id, out_sc = pl.pallas_call(
         kernel,
-        grid=(b, n_p_tiles),
+        grid=(b // block_q, n_p_tiles),
         in_specs=[
             pl.BlockSpec((block_p, i), lambda bi, pi: (pi, 0)),
             pl.BlockSpec((1, block_p), lambda bi, pi: (0, pi)),
             pl.BlockSpec((1, i), lambda bi, pi: (0, 0)),
-            pl.BlockSpec((1, i), lambda bi, pi: (bi, 0)),
-            pl.BlockSpec((1, block_p), lambda bi, pi: (bi, pi)),
-            pl.BlockSpec((1, 1), lambda bi, pi: (bi, 0)),
+            pl.BlockSpec((block_q, i), lambda bi, pi: (bi, 0)),
+            pl.BlockSpec((block_q, block_p), lambda bi, pi: (bi, pi)),
+            pl.BlockSpec((block_q, 1), lambda bi, pi: (bi, 0)),
             pl.BlockSpec((1, block_p), lambda bi, pi: (0, pi)),
         ],
         out_specs=[
-            pl.BlockSpec((1, top_n), lambda bi, pi: (bi, 0)),
-            pl.BlockSpec((1, top_n), lambda bi, pi: (bi, 0)),
+            pl.BlockSpec((block_q, top_n), lambda bi, pi: (bi, 0)),
+            pl.BlockSpec((block_q, top_n), lambda bi, pi: (bi, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, top_n), jnp.int32),
             jax.ShapeDtypeStruct((b, top_n), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((1, top_n), jnp.float32),
-            pltpu.VMEM((1, top_n), jnp.int32),
+            pltpu.VMEM((block_q, top_n), jnp.float32),
+            pltpu.VMEM((block_q, top_n), jnp.int32),
         ],
+        # The [block_p, I] similarity tile and its masked copies.
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=int(
+            min(max(32 * 2**20, 12 * block_p * i * 4), 100 * 2**20))),
         interpret=interpret,
     )(co, item_cnt_row, item_cnt_row, hist_i8, hist_i8, known_i32, ids_row)
     return out_id, out_sc

@@ -31,6 +31,7 @@ from __future__ import annotations
 import argparse
 import contextlib
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.algorithm import get_algorithm, registered
 from repro.core.pipeline import StreamConfig
 from repro.core.routing import GridSpec
@@ -58,8 +59,11 @@ def base_parser(description: str, *, grid: bool = True, caps: bool = True,
 
     ``grid=False`` omits ``--n-i`` (drivers with their own grid syntax,
     e.g. rescale's ``--from-grid/--to-grid``, or quickstart's sweep);
-    ``caps=False`` omits ``--u-cap/--i-cap/--top-n`` likewise.
+    ``caps=False`` omits ``--u-cap/--i-cap/--top-n`` likewise. Every
+    driver starts here, so this is also where the persistent compile
+    cache is switched on (``repro.compile_cache``).
     """
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=description)
     ap.add_argument("--algorithm", default=algorithm, choices=registered())
     ap.add_argument("--events", type=int, default=events)

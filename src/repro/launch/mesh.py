@@ -12,19 +12,30 @@ paper's user-group axis (``w = 16`` in its ``n_c = n_i^2 + w*n_i`` knob).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-__all__ = ["make_production_mesh", "make_cpu_mesh", "make_grid_mesh"]
+__all__ = ["make_mesh", "make_production_mesh", "make_cpu_mesh",
+           "make_grid_mesh"]
+
+
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` with ``Auto`` axes, the sharding mode this repo's
+    code is written for (propagation and ``with_sharding_constraint``).
+    Since jax 0.9 ``make_mesh`` defaults to ``Explicit`` axes, under
+    which the grid reshape in ``core/distributed.py`` and the constraints
+    in ``sharding/ctx.py`` raise."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_cpu_mesh(data: int = 1, model: int = 1):
     """Small mesh over however many (host) devices exist — used in tests."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))
 
 
 def make_grid_mesh(grid):
@@ -42,4 +53,4 @@ def make_grid_mesh(grid):
             f"S&R grid needs {needed} devices ({grid.n_i}x{grid.g}); "
             f"only {have} available"
         )
-    return jax.make_mesh((grid.g, grid.n_i), ("data", "model"))
+    return make_mesh((grid.g, grid.n_i), ("data", "model"))

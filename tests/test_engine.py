@@ -285,10 +285,20 @@ def test_shard_map_backend_matches_scan():
         a, b = a[~np.isnan(a)], b[~np.isnan(b)]
         np.testing.assert_array_equal(a, b)
         assert sm.events_processed == sc.events_processed == users.size
+        # One worker per device: every state table is split over the 4
+        # devices, one worker's slice on each.
+        import jax
+        for leaf in jax.tree.leaves(sm.final_states):
+            shards = leaf.addressable_shards
+            assert len({s.device for s in shards}) == 4, leaf.sharding
+            assert all(s.data.shape[0] == 1 for s in shards)
         print("shard_map == scan OK")
     """
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    # Host devices only: the child must never compete with the parent
+    # for an accelerator.
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
     out = subprocess.run(
         [sys.executable, "-c", textwrap.dedent(code)],

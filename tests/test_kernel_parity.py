@@ -302,3 +302,100 @@ def test_fused_topn_matches_select_on_trained_state():
     np.testing.assert_array_equal(np.asarray(got_ids), np.asarray(want_ids))
     np.testing.assert_allclose(np.asarray(got_sc), np.asarray(want_sc),
                                rtol=1e-5, atol=1e-6)
+
+
+# -- op level on colliding buckets: random worker tables ------------------
+
+
+def _random_worker_tables(rng, u_cap, i_cap):
+    """Bookkeeping tables with live ids, empty slots and live tail slots
+    (the slots padding events derive, ``slot_of(-1) = cap - 1``)."""
+    uid = rng.integers(-1, 4 * u_cap, u_cap).astype(np.int32)
+    iid = rng.integers(-1, 4 * i_cap, i_cap).astype(np.int32)
+    uid[-1] = u_cap - 1
+    iid[-1] = i_cap - 1
+    return (jnp.asarray(uid), jnp.asarray(iid),
+            jnp.asarray(rng.integers(0, 6, u_cap), jnp.int32),
+            jnp.asarray(rng.integers(0, 6, i_cap), jnp.int32),
+            jnp.asarray(rng.integers(0, 90, u_cap), jnp.int32),
+            jnp.asarray(rng.integers(0, 90, i_cap), jnp.int32),
+            jnp.asarray(90, jnp.int32))
+
+
+@pytest.mark.parametrize("seed", range(4))
+@pytest.mark.parametrize("mode", ["isgd", "bpr_pairwise", "dics"])
+def test_update_kernels_match_oracle_on_colliding_buckets(mode, seed):
+    """Buckets whose events share and steal slots (ids from a space 4x the
+    capacity, repeats within the bucket) and padding that aliases live
+    tail slots: every table the kernel path writes equals the oracle's."""
+    rng = np.random.default_rng(100 + seed)
+    u_cap, i_cap, k, e = 24, 136, 6, 48
+    tabs = _random_worker_tables(rng, u_cap, i_cap)
+    ev_u = rng.integers(0, 4 * u_cap, e).astype(np.int32)
+    ev_i = rng.integers(0, 4 * i_cap, e).astype(np.int32)
+    ev_u[5:9], ev_i[5:9] = ev_u[1], ev_i[2]     # repeats in one bucket
+    pad = rng.random(e) < 0.2
+    ev_u[pad], ev_i[pad] = -1, -1
+    ev_u, ev_i = jnp.asarray(ev_u), jnp.asarray(ev_i)
+    u_slot = state_lib.slot_of(ev_u, 1, u_cap)
+    i_slot = state_lib.slot_of(ev_i, 1, i_cap)
+    rated = jnp.asarray(rng.random((u_cap, i_cap)) < 0.3)
+    if mode == "dics":
+        co = jnp.asarray(rng.integers(0, 4, (i_cap, i_cap)), jnp.float32)
+        cnt = jnp.asarray(rng.integers(0, 9, i_cap), jnp.float32)
+        events = (ev_u, ev_i, u_slot, i_slot)
+        want = ref.dics_apply(co, cnt, rated, tabs, events)
+        got = ops.dics_update(co, cnt, rated, tabs, events, interpret=True)
+        floats_exact = True
+    else:
+        j_slot = (jnp.asarray(rng.integers(0, i_cap, e), jnp.int32)
+                  if mode == "bpr_pairwise" else None)
+        events = (ev_u, ev_i, u_slot, i_slot, j_slot,
+                  jnp.asarray(rng.normal(size=(e, k)) * 0.1, jnp.float32),
+                  jnp.asarray(rng.normal(size=(e, k)) * 0.1, jnp.float32))
+        uv = jnp.asarray(rng.normal(size=(u_cap, k)) * 0.1, jnp.float32)
+        iv = jnp.asarray(rng.normal(size=(i_cap, k)) * 0.1, jnp.float32)
+        want = ref.factor_apply(uv, iv, rated, tabs, events, eta=0.05,
+                                lam=0.01)
+        got = ops.factor_update(uv, iv, rated, tabs, events, eta=0.05,
+                                lam=0.01, interpret=True)
+        floats_exact = False
+    for j in range(2):
+        if floats_exact:
+            np.testing.assert_array_equal(np.asarray(got[j]),
+                                          np.asarray(want[j]))
+        else:
+            np.testing.assert_allclose(np.asarray(got[j]),
+                                       np.asarray(want[j]),
+                                       rtol=1e-5, atol=1e-6)
+    np.testing.assert_array_equal(np.asarray(got[2]), np.asarray(want[2]))
+    for name, a, b in zip(
+            ("user_ids", "item_ids", "user_freq", "item_freq",
+             "user_ts", "item_ts", "clock"), got[3], want[3]):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                      err_msg=f"tables.{name}")
+
+
+@pytest.mark.parametrize("batched", [False, True], ids=["one_worker", "vmap"])
+def test_clear_columns_matches_column_scatter(batched):
+    """``ops.clear_columns`` (the eviction clear of both the scan and the
+    Pallas workers) zeroes exactly the columns a masked column scatter
+    would, repeats and unflagged columns included."""
+    rng = np.random.default_rng(0)
+    n_w, u, i, e = 3, 16, 12, 5
+    table = jnp.asarray(rng.random((n_w, u, i)) < 0.5)
+    cols = jnp.asarray(rng.integers(0, i, (n_w, e)), jnp.int32)
+    clear = jnp.asarray(rng.random((n_w, e)) < 0.6)
+
+    def scatter(t, c, f):
+        for k in range(e):
+            t = t.at[:, jnp.where(f[k], c[k], i)].set(False, mode="drop")
+        return t
+
+    want = jax.vmap(scatter)(table, cols, clear)
+    if batched:
+        (got,) = jax.jit(jax.vmap(ops.clear_columns))(table, cols, clear)
+    else:
+        got = jnp.stack([ops.clear_columns(table[w], cols[w], clear[w])[0]
+                         for w in range(n_w)])
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))

@@ -30,38 +30,68 @@ def test_masked_scores_sweep(b, i, k, dtype):
                                rtol=tol, atol=tol)
 
 
+def _factor_tables(u_cap, i_cap, k):
+    """A factor worker with random vectors, rated bits and live ids."""
+    ids_u = RNG.integers(-1, 3 * u_cap, u_cap).astype(np.int32)
+    ids_i = RNG.integers(-1, 3 * i_cap, i_cap).astype(np.int32)
+    tabs = (jnp.asarray(ids_u), jnp.asarray(ids_i),
+            jnp.asarray(RNG.integers(0, 5, u_cap), jnp.int32),
+            jnp.asarray(RNG.integers(0, 5, i_cap), jnp.int32),
+            jnp.asarray(RNG.integers(0, 50, u_cap), jnp.int32),
+            jnp.asarray(RNG.integers(0, 50, i_cap), jnp.int32),
+            jnp.asarray(50, jnp.int32))
+    return (jnp.asarray(RNG.normal(size=(u_cap, k)) * 0.1, jnp.float32),
+            jnp.asarray(RNG.normal(size=(i_cap, k)) * 0.1, jnp.float32),
+            jnp.asarray(RNG.random((u_cap, i_cap)) < 0.2), tabs)
+
+
+def _assert_factor_match(got, want):
+    np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want[0]),
+                               rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(np.asarray(got[1]), np.asarray(want[1]),
+                               rtol=1e-5, atol=1e-6)
+    np.testing.assert_array_equal(np.asarray(got[2]), np.asarray(want[2]))
+    for a, b in zip(got[3], want[3]):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
 @pytest.mark.parametrize("u_cap,i_cap,k,e", [(16, 16, 4, 10), (64, 48, 10, 100),
                                              (128, 64, 32, 257)])
-def test_isgd_update_sweep(u_cap, i_cap, k, e):
-    ut = jnp.asarray(RNG.normal(size=(u_cap, k)) * 0.1, jnp.float32)
-    it = jnp.asarray(RNG.normal(size=(i_cap, k)) * 0.1, jnp.float32)
-    us = jnp.asarray(RNG.integers(0, u_cap, e), jnp.int32)
-    isl = jnp.asarray(RNG.integers(0, i_cap, e), jnp.int32)
-    val = jnp.asarray(RNG.random(e) > 0.15)
-    got_u, got_i = ops.isgd_update(ut, it, us, isl, val, eta=0.05,
-                                  lam=0.01, interpret=True)
-    want_u, want_i = ref.isgd_apply(ut, it, us, isl, val, eta=0.05, lam=0.01)
-    np.testing.assert_allclose(np.asarray(got_u), np.asarray(want_u),
-                               rtol=1e-5, atol=1e-6)
-    np.testing.assert_allclose(np.asarray(got_i), np.asarray(want_i),
-                               rtol=1e-5, atol=1e-6)
+def test_factor_update_sweep(u_cap, i_cap, k, e):
+    uv, iv, rated, tabs = _factor_tables(u_cap, i_cap, k)
+    ev_u = RNG.integers(0, 3 * u_cap, e).astype(np.int32)
+    ev_i = RNG.integers(0, 3 * i_cap, e).astype(np.int32)
+    pad = RNG.random(e) < 0.15
+    ev_u[pad], ev_i[pad] = -1, -1
+    ev_u, ev_i = jnp.asarray(ev_u), jnp.asarray(ev_i)
+    events = (ev_u, ev_i, ev_u % u_cap, ev_i % i_cap, None,
+              jnp.asarray(RNG.normal(size=(e, k)) * 0.1, jnp.float32),
+              jnp.asarray(RNG.normal(size=(e, k)) * 0.1, jnp.float32))
+    got = ops.factor_update(uv, iv, rated, tabs, events, eta=0.05,
+                            lam=0.01, interpret=True)
+    want = ref.factor_apply(uv, iv, rated, tabs, events, eta=0.05, lam=0.01)
+    _assert_factor_match(got, want)
 
 
-def test_isgd_sequential_dependency():
+def test_factor_update_sequential_dependency():
     """Events touching the same rows must apply in order (not parallel)."""
-    k = 4
-    ut = jnp.ones((4, k), jnp.float32) * 0.3
-    it = jnp.ones((4, k), jnp.float32) * 0.3
-    us = jnp.zeros((8,), jnp.int32)
-    isl = jnp.zeros((8,), jnp.int32)
-    val = jnp.ones((8,), bool)
-    got_u, got_i = ops.isgd_update(ut, it, us, isl, val, eta=0.1,
-                                  lam=0.0, interpret=True)
-    want_u, want_i = ref.isgd_apply(ut, it, us, isl, val, eta=0.1, lam=0.0)
-    np.testing.assert_allclose(np.asarray(got_u), np.asarray(want_u),
-                               rtol=1e-5)
-    np.testing.assert_allclose(np.asarray(got_i), np.asarray(want_i),
-                               rtol=1e-5)
+    k, e = 4, 8
+    uv = jnp.ones((4, k), jnp.float32) * 0.3
+    iv = jnp.ones((4, k), jnp.float32) * 0.3
+    rated = jnp.zeros((4, 4), bool)
+    tabs = (jnp.asarray([0, -1, -1, -1], jnp.int32),
+            jnp.asarray([0, -1, -1, -1], jnp.int32),
+            jnp.ones((4,), jnp.int32), jnp.ones((4,), jnp.int32),
+            jnp.zeros((4,), jnp.int32), jnp.zeros((4,), jnp.int32),
+            jnp.asarray(0, jnp.int32))
+    zeros = jnp.zeros((e,), jnp.int32)
+    init = jnp.zeros((e, k), jnp.float32)
+    events = (zeros, zeros, zeros, zeros, None, init, init)
+    got = ops.factor_update(uv, iv, rated, tabs, events, eta=0.1, lam=0.0,
+                            interpret=True)
+    want = ref.factor_apply(uv, iv, rated, tabs, events, eta=0.1, lam=0.0)
+    _assert_factor_match(got, want)
+    assert int(got[3][2][0]) == 1 + e          # one LFU touch per event
 
 
 @pytest.mark.parametrize("hq,hkv", [(4, 4), (4, 2), (8, 1)])

@@ -17,6 +17,9 @@ ROOT = os.path.join(os.path.dirname(__file__), "..")
 def run_sub(code: str, devices: int = 8, timeout: int = 900):
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
+    # The child simulates its mesh on host devices and must never compete
+    # with the parent for an accelerator.
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
     out = subprocess.run(
         [sys.executable, "-c", textwrap.dedent(code)],
@@ -34,9 +37,10 @@ def test_grid_step_matches_vmap_pipeline():
         from repro.core import distributed as dist, routing
         from repro.core.disgd import DisgdHyper
         from repro.core.pipeline import StreamConfig, make_worker_step, init_states
+        from repro.launch.mesh import make_mesh
 
         # model axis = item splits (n_i=2), data axis = user groups (g=4).
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         n_i = mesh.shape["model"]; g = mesh.shape["data"]
         grid = routing.GridSpec(n_i, g - n_i)  # n_c = 8 workers
         cfg = StreamConfig(algorithm="disgd", grid=grid, micro_batch=256,
@@ -87,10 +91,11 @@ def test_small_mesh_train_step_runs_sharded():
         from repro.optim import adamw_init
         from repro.sharding import specs as specs_lib
         from repro.sharding.ctx import use_mesh
+        from repro.launch.mesh import make_mesh
 
         cfg = get_smoke_config("olmoe_1b_7b")
         bundle = build(cfg)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         with use_mesh(mesh):
             params = bundle.init(jax.random.key(0))
             shard = jax.tree.map(lambda s: NamedSharding(mesh, s),
@@ -123,10 +128,11 @@ def test_dryrun_machinery_on_8_devices():
         from repro.roofline import analyze_compiled
         from repro.sharding import specs as specs_lib
         from repro.sharding.ctx import use_mesh
+        from repro.launch.mesh import make_mesh
 
         cfg = get_smoke_config("h2o_danube_1p8b")
         bundle = build(cfg)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         shape = InputShape("t", 64, 4, "prefill")
         with use_mesh(mesh):
             pshapes = mod.param_shapes(bundle.decls)
