@@ -41,6 +41,7 @@ from repro.core.algorithm import Algorithm, register
 from repro.core.disgd import init_vector, score_items
 from repro.core.serve import partial_topn
 from repro.core.state import DisgdState, Tables
+from repro.obs import trace as trace_lib
 
 __all__ = ["BprHyper", "bpr_worker_step", "make_pallas_worker",
            "BprAlgorithm"]
@@ -210,32 +211,36 @@ def make_pallas_worker(hyper: BprHyper, key: jax.Array):
         ev_u, ev_i = events
         valid = ev_u >= 0
         t = st.tables
-        u_slot = state_lib.slot_of(ev_u, hyper.g, u_cap)
-        i_slot = state_lib.slot_of(ev_i, hyper.n_i, i_cap)
-        known_u = t.user_ids[u_slot] == ev_u
-        known_i = t.item_ids[i_slot] == ev_i
+        with trace_lib.layer("score"):
+            u_slot = state_lib.slot_of(ev_u, hyper.g, u_cap)
+            i_slot = state_lib.slot_of(ev_i, hyper.n_i, i_cap)
+            known_u = t.user_ids[u_slot] == ev_u
+            known_i = t.item_ids[i_slot] == ev_i
 
-        init_u = init_batch(ev_u)
-        init_i = init_batch(ev_i)
+            init_u = init_batch(ev_u)
+            init_i = init_batch(ev_i)
 
-        # --- recommend (batched masked scoring, bucket-start state) ---
-        u_vecs_b = jnp.where(known_u[:, None], st.user_vecs[u_slot], init_u)
-        rated_rows = jnp.where(known_u[:, None], st.rated[u_slot], False)
-        cand = (t.item_ids >= 0)[None, :] & ~rated_rows & valid[:, None]
-        scores = ops.masked_scores(u_vecs_b, st.item_vecs, cand)
-        top_scores, top_idx = jax.lax.top_k(
-            scores, min(hyper.top_n, scores.shape[-1])
-        )
-        hits = jnp.any(
-            (t.item_ids[top_idx] == ev_i[:, None]) & jnp.isfinite(top_scores),
-            axis=-1,
-        ) & valid & known_i
+            # --- recommend (batched masked scoring, bucket-start state) ---
+            u_vecs_b = jnp.where(known_u[:, None], st.user_vecs[u_slot],
+                                 init_u)
+            rated_rows = jnp.where(known_u[:, None], st.rated[u_slot], False)
+            cand = (t.item_ids >= 0)[None, :] & ~rated_rows & valid[:, None]
+            scores = ops.masked_scores(u_vecs_b, st.item_vecs, cand)
+            top_scores, top_idx = jax.lax.top_k(
+                scores, min(hyper.top_n, scores.shape[-1])
+            )
+            hits = jnp.any(
+                (t.item_ids[top_idx] == ev_i[:, None])
+                & jnp.isfinite(top_scores),
+                axis=-1,
+            ) & valid & known_i
 
         # --- negative replay: the clock each event sees is bucket-start
         # clock + #valid events before it ---
-        vi = valid.astype(t.clock.dtype)
-        clocks = t.clock + jnp.cumsum(vi) - vi
-        j_slot = jax.vmap(sample_neg)(clocks, ev_u)
+        with trace_lib.layer("update"):
+            vi = valid.astype(t.clock.dtype)
+            clocks = t.clock + jnp.cumsum(vi) - vi
+            j_slot = jax.vmap(sample_neg)(clocks, ev_u)
 
         # --- train (fused pairwise update: exact reference semantics) ---
         uv, iv, rated, tabs = ops.factor_update(

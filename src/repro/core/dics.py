@@ -31,6 +31,7 @@ import jax.numpy as jnp
 from repro.core import state as state_lib
 from repro.core.state import DicsState, Tables
 from repro.kernels import ops
+from repro.obs import trace as trace_lib
 
 __all__ = ["DicsHyper", "dics_worker_step", "make_pallas_worker",
            "dics_scores", "dics_partial_topn", "similarity_matrix"]
@@ -145,42 +146,44 @@ def dics_worker_step(state: DicsState, events, hyper: DicsHyper):
         new_u = t.user_ids[u_slot] != u_id
         new_i = t.item_ids[i_slot] != i_id
 
-        # Collision eviction (no-op when capacity covers the id space).
-        st = jax.lax.cond(
-            new_u,
-            lambda s: s._replace(rated=s.rated.at[u_slot, :].set(False)),
-            lambda s: s,
-            st,
-        )
-        st = jax.lax.cond(
-            new_i,
-            lambda s: s._replace(
-                rated=s.rated.at[:, i_slot].set(False),
-                co=s.co.at[i_slot, :].set(0.0).at[:, i_slot].set(0.0),
-                item_cnt=s.item_cnt.at[i_slot].set(0.0),
-            ),
-            lambda s: s,
-            st,
-        )
-
-        rated_row = st.rated[u_slot]
-
-        # --- recommend, then evaluate ---
-        scores = dics_scores(
-            st.co, st.item_cnt, rated_row, st.tables.item_ids, hyper.k_nn
-        )
-        top_scores, top_idx = jax.lax.top_k(
-            scores, min(hyper.top_n, scores.shape[-1])
-        )
-        hit = (
-            jnp.any(
-                (st.tables.item_ids[top_idx] == i_id)
-                & jnp.isfinite(top_scores)
-                & (top_scores > 0)
+        with trace_lib.layer("update"):
+            # Collision eviction (no-op when capacity covers the id space).
+            st = jax.lax.cond(
+                new_u,
+                lambda s: s._replace(rated=s.rated.at[u_slot, :].set(False)),
+                lambda s: s,
+                st,
             )
-            & valid
-            & ~new_i
-        )
+            st = jax.lax.cond(
+                new_i,
+                lambda s: s._replace(
+                    rated=s.rated.at[:, i_slot].set(False),
+                    co=s.co.at[i_slot, :].set(0.0).at[:, i_slot].set(0.0),
+                    item_cnt=s.item_cnt.at[i_slot].set(0.0),
+                ),
+                lambda s: s,
+                st,
+            )
+
+        with trace_lib.layer("score"):
+            rated_row = st.rated[u_slot]
+
+            # --- recommend, then evaluate ---
+            scores = dics_scores(
+                st.co, st.item_cnt, rated_row, st.tables.item_ids, hyper.k_nn
+            )
+            top_scores, top_idx = jax.lax.top_k(
+                scores, min(hyper.top_n, scores.shape[-1])
+            )
+            hit = (
+                jnp.any(
+                    (st.tables.item_ids[top_idx] == i_id)
+                    & jnp.isfinite(top_scores)
+                    & (top_scores > 0)
+                )
+                & valid
+                & ~new_i
+            )
 
         # --- incremental update (Eq. 6 statistics) ---
         def write(st: DicsState) -> DicsState:
@@ -208,7 +211,8 @@ def dics_worker_step(state: DicsState, events, hyper: DicsHyper):
                 rated=st.rated.at[u_slot, i_slot].set(True),
             )
 
-        st = jax.lax.cond(valid, write, lambda s: s, st)
+        with trace_lib.layer("update"):
+            st = jax.lax.cond(valid, write, lambda s: s, st)
         return st, (hit, valid)
 
     state, (hits, evaluated) = jax.lax.scan(body, state, (u_ids, i_ids))
@@ -236,33 +240,35 @@ def make_pallas_worker(hyper: DicsHyper):
         ev_u, ev_i = events
         valid = ev_u >= 0
         t = st.tables
-        u_slot = state_lib.slot_of(ev_u, hyper.g, u_cap)
-        i_slot = state_lib.slot_of(ev_i, hyper.n_i, i_cap)
-        known_u = t.user_ids[u_slot] == ev_u
-        known_i = t.item_ids[i_slot] == ev_i
+        with trace_lib.layer("score"):
+            u_slot = state_lib.slot_of(ev_u, hyper.g, u_cap)
+            i_slot = state_lib.slot_of(ev_i, hyper.n_i, i_cap)
+            known_u = t.user_ids[u_slot] == ev_u
+            known_i = t.item_ids[i_slot] == ev_i
 
-        # --- recommend (Eq. 6 once per bucket, Eq. 7 batched) ---
-        sim = similarity_matrix(st.co, st.item_cnt)       # [I, I]
-        rated_rows = st.rated[u_slot] & known_u[:, None]  # [E, I]
+            # --- recommend (Eq. 6 once per bucket, Eq. 7 batched) ---
+            sim = similarity_matrix(st.co, st.item_cnt)       # [I, I]
+            rated_rows = st.rated[u_slot] & known_u[:, None]  # [E, I]
 
-        def score_chunk(rows):
-            return jax.vmap(lambda r: dics_scores(
-                st.co, st.item_cnt, r, t.item_ids, hyper.k_nn, sim=sim))(rows)
+            def score_chunk(rows):
+                return jax.vmap(lambda r: dics_scores(
+                    st.co, st.item_cnt, r, t.item_ids, hyper.k_nn,
+                    sim=sim))(rows)
 
-        n_ev = ev_u.shape[0]
-        chunk = max(1, min(n_ev, (1 << 22) // max(1, i_cap * i_cap)))
-        while n_ev % chunk:
-            chunk -= 1
-        scores = jax.lax.map(
-            score_chunk, rated_rows.reshape(n_ev // chunk, chunk, i_cap)
-        ).reshape(n_ev, i_cap)
-        top_scores, top_idx = jax.lax.top_k(
-            scores, min(hyper.top_n, scores.shape[-1]))
-        hits = jnp.any(
-            (t.item_ids[top_idx] == ev_i[:, None])
-            & jnp.isfinite(top_scores) & (top_scores > 0),
-            axis=-1,
-        ) & valid & known_i
+            n_ev = ev_u.shape[0]
+            chunk = max(1, min(n_ev, (1 << 22) // max(1, i_cap * i_cap)))
+            while n_ev % chunk:
+                chunk -= 1
+            scores = jax.lax.map(
+                score_chunk, rated_rows.reshape(n_ev // chunk, chunk, i_cap)
+            ).reshape(n_ev, i_cap)
+            top_scores, top_idx = jax.lax.top_k(
+                scores, min(hyper.top_n, scores.shape[-1]))
+            hits = jnp.any(
+                (t.item_ids[top_idx] == ev_i[:, None])
+                & jnp.isfinite(top_scores) & (top_scores > 0),
+                axis=-1,
+            ) & valid & known_i
 
         # --- update (fused sequential op: exact reference semantics) ---
         co, cnt, rated, tabs = ops.dics_update(

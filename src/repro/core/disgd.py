@@ -33,6 +33,7 @@ import jax.numpy as jnp
 
 from repro.core import state as state_lib
 from repro.core.state import DisgdState, Tables
+from repro.obs import trace as trace_lib
 
 __all__ = ["DisgdHyper", "disgd_worker_step", "make_pallas_worker",
            "init_vector", "score_items"]
@@ -114,74 +115,78 @@ def disgd_worker_step(state: DisgdState, events, hyper: DisgdHyper, key: jax.Arr
     # Replica-consistent init vectors for the whole bucket in one batched
     # PRNG pass (fold_in per id, so values are identical to per-event
     # computation; unused lanes are discarded by the masks below).
-    init_us = jax.vmap(
-        lambda ident: init_vector(key, ident, hyper.k, hyper.init_scale)
-    )(u_ids)
-    init_is = jax.vmap(
-        lambda ident: init_vector(key, ident, hyper.k, hyper.init_scale)
-    )(i_ids)
+    with trace_lib.layer("score"):
+        init_us = jax.vmap(
+            lambda ident: init_vector(key, ident, hyper.k, hyper.init_scale)
+        )(u_ids)
+        init_is = jax.vmap(
+            lambda ident: init_vector(key, ident, hyper.k, hyper.init_scale)
+        )(i_ids)
 
     def body(st: DisgdState, ev):
         u_id, i_id, init_u, init_i = ev
         valid = u_id >= 0
         t = st.tables
 
-        u_slot = state_lib.slot_of(u_id, hyper.g, hyper.u_cap)
-        i_slot = state_lib.slot_of(i_id, hyper.n_i, hyper.i_cap)
+        with trace_lib.layer("score"):
+            u_slot = state_lib.slot_of(u_id, hyper.g, hyper.u_cap)
+            i_slot = state_lib.slot_of(i_id, hyper.n_i, hyper.i_cap)
 
-        new_u = t.user_ids[u_slot] != u_id
-        new_i = t.item_ids[i_slot] != i_id
+            new_u = t.user_ids[u_slot] != u_id
+            new_i = t.item_ids[i_slot] != i_id
 
-        u_vec = jnp.where(new_u, init_u, st.user_vecs[u_slot])
-        i_vec = jnp.where(new_i, init_i, st.item_vecs[i_slot])
-        # A reused slot may carry the previous tenant's history: mask it.
-        rated_row = jnp.where(new_u, False, st.rated[u_slot])
-        rated_row = rated_row.at[i_slot].set(
-            jnp.where(new_i, False, rated_row[i_slot])
-        )
+            u_vec = jnp.where(new_u, init_u, st.user_vecs[u_slot])
+            i_vec = jnp.where(new_i, init_i, st.item_vecs[i_slot])
+            # A reused slot may carry the previous tenant's history: mask it.
+            rated_row = jnp.where(new_u, False, st.rated[u_slot])
+            rated_row = rated_row.at[i_slot].set(
+                jnp.where(new_i, False, rated_row[i_slot])
+            )
 
-        # --- recommend, then evaluate (Alg. 4 lines 1-5) ---
-        hit = _recommend_hit(
-            u_vec, st.item_vecs, t.item_ids, rated_row, i_id, hyper.top_n
-        ) & valid & ~new_i  # a never-seen item cannot be recommended
+            # --- recommend, then evaluate (Alg. 4 lines 1-5) ---
+            hit = _recommend_hit(
+                u_vec, st.item_vecs, t.item_ids, rated_row, i_id, hyper.top_n
+            ) & valid & ~new_i  # a never-seen item cannot be recommended
 
-        # --- incremental SGD update (Alg. 2) ---
-        err = 1.0 - jnp.dot(u_vec, i_vec)
-        u_new = u_vec + hyper.eta * (err * i_vec - hyper.lam * u_vec)
-        i_new = i_vec + hyper.eta * (err * u_vec - hyper.lam * i_vec)
+        with trace_lib.layer("update"):
+            # --- incremental SGD update (Alg. 2) ---
+            err = 1.0 - jnp.dot(u_vec, i_vec)
+            u_new = u_vec + hyper.eta * (err * i_vec - hyper.lam * u_vec)
+            i_new = i_vec + hyper.eta * (err * u_vec - hyper.lam * i_vec)
 
-        # --- masked writes: padding events scatter out-of-bounds and are
-        # skipped by mode="drop" (cheaper than gather + select + write) ---
-        w = valid
-        wu = jnp.where(w, u_slot, hyper.u_cap)    # drop target on padding
-        wi = jnp.where(w, i_slot, hyper.i_cap)
-        clock = t.clock + w.astype(t.clock.dtype)
-        tables = t._replace(
-            user_ids=t.user_ids.at[wu].set(u_id, mode="drop"),
-            item_ids=t.item_ids.at[wi].set(i_id, mode="drop"),
-            user_freq=t.user_freq.at[wu].set(
-                jnp.where(new_u, 1, t.user_freq[u_slot] + 1), mode="drop"),
-            item_freq=t.item_freq.at[wi].set(
-                jnp.where(new_i, 1, t.item_freq[i_slot] + 1), mode="drop"),
-            user_ts=t.user_ts.at[wu].set(clock, mode="drop"),
-            item_ts=t.item_ts.at[wi].set(clock, mode="drop"),
-            clock=clock,
-        )
-        # Collision eviction: clear the evicted item's column, then the
-        # evicted user's row, then mark the rated pair (same order as the
-        # hash-map semantics; no-ops when capacity covers the id space).
-        (rated,) = ops.clear_columns(st.rated, i_slot[None],
-                                     (w & new_i)[None])
-        row = jnp.where(w & new_u, False, rated[u_slot])
-        row = row.at[jnp.where(w, i_slot, hyper.i_cap)].set(True, mode="drop")
-        rated = rated.at[wu].set(row, mode="drop")
+            # --- masked writes: padding events scatter out-of-bounds and are
+            # skipped by mode="drop" (cheaper than gather + select + write) ---
+            w = valid
+            wu = jnp.where(w, u_slot, hyper.u_cap)    # drop target on padding
+            wi = jnp.where(w, i_slot, hyper.i_cap)
+            clock = t.clock + w.astype(t.clock.dtype)
+            tables = t._replace(
+                user_ids=t.user_ids.at[wu].set(u_id, mode="drop"),
+                item_ids=t.item_ids.at[wi].set(i_id, mode="drop"),
+                user_freq=t.user_freq.at[wu].set(
+                    jnp.where(new_u, 1, t.user_freq[u_slot] + 1), mode="drop"),
+                item_freq=t.item_freq.at[wi].set(
+                    jnp.where(new_i, 1, t.item_freq[i_slot] + 1), mode="drop"),
+                user_ts=t.user_ts.at[wu].set(clock, mode="drop"),
+                item_ts=t.item_ts.at[wi].set(clock, mode="drop"),
+                clock=clock,
+            )
+            # Collision eviction: clear the evicted item's column, then the
+            # evicted user's row, then mark the rated pair (same order as the
+            # hash-map semantics; no-ops when capacity covers the id space).
+            (rated,) = ops.clear_columns(st.rated, i_slot[None],
+                                         (w & new_i)[None])
+            row = jnp.where(w & new_u, False, rated[u_slot])
+            row = row.at[jnp.where(w, i_slot, hyper.i_cap)].set(
+                True, mode="drop")
+            rated = rated.at[wu].set(row, mode="drop")
 
-        st = DisgdState(
-            tables=tables,
-            user_vecs=st.user_vecs.at[wu].set(u_new, mode="drop"),
-            item_vecs=st.item_vecs.at[wi].set(i_new, mode="drop"),
-            rated=rated,
-        )
+            st = DisgdState(
+                tables=tables,
+                user_vecs=st.user_vecs.at[wu].set(u_new, mode="drop"),
+                item_vecs=st.item_vecs.at[wi].set(i_new, mode="drop"),
+                rated=rated,
+            )
         return st, (hit, valid)
 
     state, (hits, evaluated) = jax.lax.scan(
@@ -220,27 +225,30 @@ def make_pallas_worker(hyper: DisgdHyper, key: jax.Array):
         ev_u, ev_i = events
         valid = ev_u >= 0
         t = st.tables
-        u_slot = state_lib.slot_of(ev_u, hyper.g, u_cap)
-        i_slot = state_lib.slot_of(ev_i, hyper.n_i, i_cap)
-        # "Known at bucket start": the slot already holds this exact id.
-        known_u = t.user_ids[u_slot] == ev_u
-        known_i = t.item_ids[i_slot] == ev_i
+        with trace_lib.layer("score"):
+            u_slot = state_lib.slot_of(ev_u, hyper.g, u_cap)
+            i_slot = state_lib.slot_of(ev_i, hyper.n_i, i_cap)
+            # "Known at bucket start": the slot already holds this exact id.
+            known_u = t.user_ids[u_slot] == ev_u
+            known_i = t.item_ids[i_slot] == ev_i
 
-        init_u = init_batch(ev_u)                       # [cap, k]
-        init_i = init_batch(ev_i)
+            init_u = init_batch(ev_u)                       # [cap, k]
+            init_i = init_batch(ev_i)
 
-        # --- recommend (batched masked scoring) ---
-        u_vecs_b = jnp.where(known_u[:, None], st.user_vecs[u_slot], init_u)
-        rated_rows = jnp.where(known_u[:, None], st.rated[u_slot], False)
-        cand = (t.item_ids >= 0)[None, :] & ~rated_rows & valid[:, None]
-        scores = ops.masked_scores(u_vecs_b, st.item_vecs, cand)
-        top_scores, top_idx = jax.lax.top_k(
-            scores, min(hyper.top_n, scores.shape[-1])
-        )
-        hits = jnp.any(
-            (t.item_ids[top_idx] == ev_i[:, None]) & jnp.isfinite(top_scores),
-            axis=-1,
-        ) & valid & known_i
+            # --- recommend (batched masked scoring) ---
+            u_vecs_b = jnp.where(known_u[:, None], st.user_vecs[u_slot],
+                                 init_u)
+            rated_rows = jnp.where(known_u[:, None], st.rated[u_slot], False)
+            cand = (t.item_ids >= 0)[None, :] & ~rated_rows & valid[:, None]
+            scores = ops.masked_scores(u_vecs_b, st.item_vecs, cand)
+            top_scores, top_idx = jax.lax.top_k(
+                scores, min(hyper.top_n, scores.shape[-1])
+            )
+            hits = jnp.any(
+                (t.item_ids[top_idx] == ev_i[:, None])
+                & jnp.isfinite(top_scores),
+                axis=-1,
+            ) & valid & known_i
 
         # --- train (fused complete-update op: exact reference semantics) ---
         uv, iv, rated, tabs = ops.factor_update(

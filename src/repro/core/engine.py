@@ -63,6 +63,7 @@ from repro.core.evaluator import RecallAccumulator
 from repro.drift import controller as controller_lib
 from repro.drift import detector as detector_lib
 from repro.obs import telemetry as telemetry_lib
+from repro.obs import trace as trace_lib
 
 __all__ = ["make_worker_fn", "make_pallas_worker_fn", "run_stream_device",
            "PublishEvent", "scan_steps", "compiled_scan"]
@@ -175,103 +176,116 @@ def _make_batch_step(cfg, worker_fn):
     tel_on = cfg.telemetry
 
     def _occ_total(s):
-        u, i = occ_fn(s)
-        return (jnp.sum(u) + jnp.sum(i)).astype(jnp.int32)
+        with trace_lib.layer("telemetry"):
+            u, i = occ_fn(s)
+            return (jnp.sum(u) + jnp.sum(i)).astype(jnp.int32)
 
     def live(carry, fresh):
         (states, cu, ci, since, processed, dropped, forgets, det, boost,
          tel) = carry
         fu, fi = fresh
-        bu = jnp.concatenate([cu, fu])
-        bi = jnp.concatenate([ci, fi])
-        valid = bu >= 0
-        # Invalid slots route to key n_c: out of range, so they occupy no
-        # bucket capacity and contribute no load.
-        keys = jnp.where(valid, (bi % n_i) * g + (bu % g), n_c)
-        buckets, kept, load = routing.bucket_dispatch(
-            keys.astype(jnp.int32), n_c, cap
-        )
-        kept = kept & valid
+        with trace_lib.layer("route"):
+            bu = jnp.concatenate([cu, fu])
+            bi = jnp.concatenate([ci, fi])
+            valid = bu >= 0
+            # Invalid slots route to key n_c: out of range, so they occupy
+            # no bucket capacity and contribute no load.
+            keys = jnp.where(valid, (bi % n_i) * g + (bu % g), n_c)
+            buckets, kept, load = routing.bucket_dispatch(
+                keys.astype(jnp.int32), n_c, cap
+            )
+            kept = kept & valid
 
-        ev_u = jnp.where(buckets >= 0, bu[jnp.clip(buckets, 0, None)], -1)
-        ev_i = jnp.where(buckets >= 0, bi[jnp.clip(buckets, 0, None)], -1)
+            ev_u = jnp.where(buckets >= 0, bu[jnp.clip(buckets, 0, None)], -1)
+            ev_i = jnp.where(buckets >= 0, bi[jnp.clip(buckets, 0, None)], -1)
         # Precision@N denominator, measured on the bucket-start states
         # (before this batch trains) — the same expression the host loop
         # folds, so the two backends stay bit-identical.
         list_len = 0
         if tel_on:
-            list_len = telemetry_lib.effective_list_len(
-                states, ev_u.astype(jnp.int32),
-                top_n=cfg.resolved_hyper().top_n, g=g, storage=cfg.storage)
+            with trace_lib.layer("telemetry"):
+                list_len = telemetry_lib.effective_list_len(
+                    states, ev_u.astype(jnp.int32),
+                    top_n=cfg.resolved_hyper().top_n, g=g,
+                    storage=cfg.storage)
         states, hits, evaluated = worker_fn(
             states, ev_u.astype(jnp.int32), ev_i.astype(jnp.int32)
         )
 
-        # Stream-order recall bits for this step (NaN = no evaluation).
-        flat_idx = buckets.reshape(-1)
-        sel = (flat_idx >= 0) & evaluated.reshape(-1)
-        bits = jnp.full((layout,), jnp.nan, jnp.float32).at[
-            jnp.where(sel, flat_idx, layout)
-        ].set(jnp.where(sel, hits.reshape(-1).astype(jnp.float32), 0.0),
-              mode="drop")
+        with trace_lib.layer("route"):
+            # Stream-order recall bits for this step (NaN = no evaluation).
+            flat_idx = buckets.reshape(-1)
+            sel = (flat_idx >= 0) & evaluated.reshape(-1)
+            bits = jnp.full((layout,), jnp.nan, jnp.float32).at[
+                jnp.where(sel, flat_idx, layout)
+            ].set(jnp.where(sel, hits.reshape(-1).astype(jnp.float32), 0.0),
+                  mode="drop")
 
-        # Overflow re-queue (order-preserving compaction into the carry
-        # buffer); anything past the buffer is dropped and counted.
-        overflow = valid & ~kept
-        ovf_idx = jnp.nonzero(overflow, size=carry_cap, fill_value=layout)[0]
-        bu_ext = jnp.concatenate([bu, jnp.full((1,), -1, bu.dtype)])
-        bi_ext = jnp.concatenate([bi, jnp.full((1,), -1, bi.dtype)])
-        cu_new = bu_ext[jnp.minimum(ovf_idx, layout)]
-        ci_new = bi_ext[jnp.minimum(ovf_idx, layout)]
-        n_overflow = jnp.sum(overflow.astype(jnp.int32))
-        dropped = dropped + jnp.maximum(0, n_overflow - carry_cap)
+            # Overflow re-queue (order-preserving compaction into the carry
+            # buffer); anything past the buffer is dropped and counted.
+            overflow = valid & ~kept
+            ovf_idx = jnp.nonzero(overflow, size=carry_cap,
+                                  fill_value=layout)[0]
+            bu_ext = jnp.concatenate([bu, jnp.full((1,), -1, bu.dtype)])
+            bi_ext = jnp.concatenate([bi, jnp.full((1,), -1, bi.dtype)])
+            cu_new = bu_ext[jnp.minimum(ovf_idx, layout)]
+            ci_new = bi_ext[jnp.minimum(ovf_idx, layout)]
+            n_overflow = jnp.sum(overflow.astype(jnp.int32))
+            dropped = dropped + jnp.maximum(0, n_overflow - carry_cap)
 
-        kept_n = jnp.sum(kept.astype(jnp.int32))
-        processed = processed + kept_n
-        since = since + kept_n
+            kept_n = jnp.sum(kept.astype(jnp.int32))
+            processed = processed + kept_n
+            since = since + kept_n
         fired = jnp.zeros((), jnp.int32)
         evicted = jnp.zeros((), jnp.int32)
         if adaptive:
-            det = detector_lib.detector_update(
-                det, hits, evaluated, cfg.drift.detector)
-            if tel_on:
-                occ_before = _occ_total(states)
-            states, boost = controller(states, det.fired, boost)
-            if tel_on:
-                # Controller decay shrinks weights without freeing rows;
-                # only the net occupancy drop counts as evictions.
-                evicted = jnp.maximum(occ_before - _occ_total(states), 0)
-            forgets = forgets + det.fired.astype(jnp.int32)
-            fired = det.fired.astype(jnp.int32)
+            with trace_lib.layer("forget"):
+                det = detector_lib.detector_update(
+                    det, hits, evaluated, cfg.drift.detector)
+                if tel_on:
+                    occ_before = _occ_total(states)
+                states, boost = controller(states, det.fired, boost)
+                if tel_on:
+                    # Controller decay shrinks weights without freeing
+                    # rows; only the net occupancy drop counts as
+                    # evictions.
+                    evicted = jnp.maximum(occ_before - _occ_total(states), 0)
+                forgets = forgets + det.fired.astype(jnp.int32)
+                fired = det.fired.astype(jnp.int32)
         elif forget is not None:
-            trigger = since >= cfg.forgetting.trigger_every
-            if tel_on:
-                def _forget_counted(s):
-                    before = _occ_total(s)
-                    s2 = forget(s)
-                    return s2, before - _occ_total(s2)
+            with trace_lib.layer("forget"):
+                trigger = since >= cfg.forgetting.trigger_every
+                if tel_on:
+                    def _forget_counted(s):
+                        before = _occ_total(s)
+                        s2 = forget(s)
+                        return s2, before - _occ_total(s2)
 
-                states, evicted = jax.lax.cond(
-                    trigger, _forget_counted,
-                    lambda s: (s, jnp.zeros((), jnp.int32)), states)
-            else:
-                states = jax.lax.cond(trigger, forget, lambda s: s, states)
-            # Carry the remainder instead of resetting to zero: a reset
-            # aliases the cadence onto micro-batch boundaries whenever
-            # ``trigger_every`` is not a multiple of the micro-batch
-            # (triggers fire every ceil(te/mb)*mb events instead of every
-            # te) — with the remainder carried, trigger counts match
-            # floor(processed / trigger_every) exactly for mb <= te.
-            since = jnp.where(trigger, since - cfg.forgetting.trigger_every,
-                              since)
-            forgets = forgets + trigger.astype(jnp.int32)
+                    states, evicted = jax.lax.cond(
+                        trigger, _forget_counted,
+                        lambda s: (s, jnp.zeros((), jnp.int32)), states)
+                else:
+                    states = jax.lax.cond(trigger, forget, lambda s: s,
+                                          states)
+                # Carry the remainder instead of resetting to zero: a reset
+                # aliases the cadence onto micro-batch boundaries whenever
+                # ``trigger_every`` is not a multiple of the micro-batch
+                # (triggers fire every ceil(te/mb)*mb events instead of
+                # every te) — with the remainder carried, trigger counts
+                # match floor(processed / trigger_every) exactly for
+                # mb <= te.
+                since = jnp.where(trigger,
+                                  since - cfg.forgetting.trigger_every, since)
+                forgets = forgets + trigger.astype(jnp.int32)
 
         if tel_on:
-            u_o, i_o = occ_fn(states)
-            tel = telemetry_lib.telemetry_batch_update(
-                tel, kept=kept_n, overflow=n_overflow, carry_cap=carry_cap,
-                evicted=evicted, hits=hits, evaluated=evaluated, load=load,
-                occupancy=u_o + i_o, list_len=list_len)
+            with trace_lib.layer("telemetry"):
+                u_o, i_o = occ_fn(states)
+                tel = telemetry_lib.telemetry_batch_update(
+                    tel, kept=kept_n, overflow=n_overflow,
+                    carry_cap=carry_cap, evicted=evicted, hits=hits,
+                    evaluated=evaluated, load=load, occupancy=u_o + i_o,
+                    list_len=list_len)
 
         carry = (states, cu_new, ci_new, since, processed, dropped, forgets,
                  det, boost, tel)
@@ -291,10 +305,20 @@ def _make_batch_step(cfg, worker_fn):
         cu = carry[1]
         has_work = jnp.any(fu >= 0) | jnp.any(cu >= 0)
         carry, outs = jax.lax.cond(has_work, live, dead, carry, fresh)
-        u_occ, i_occ = occ_fn(carry[0])
+        with trace_lib.layer("telemetry"):
+            u_occ, i_occ = occ_fn(carry[0])
         return carry, outs + (u_occ, i_occ)
 
     return batch_step, carry_cap, cap
+
+
+def _scan_program(batch_step):
+    """The jitted scan over micro-batches; named, so its module reads
+    ``jit_engine_scan`` in device traces and compiled text."""
+    def engine_scan(carry, xs):
+        return jax.lax.scan(batch_step, carry, xs)
+
+    return jax.jit(engine_scan)
 
 
 def init_scan_carry(cfg, states=None, carry=(None, None), detector=None):
@@ -351,8 +375,9 @@ def compiled_scan(cfg, steps: int):
     carry0 = jax.eval_shape(lambda: init_scan_carry(cfg))
     mb = cfg.micro_batch
     xs = (jax.ShapeDtypeStruct((steps, mb), jnp.int32),) * 2
-    run = jax.jit(lambda c, x: jax.lax.scan(batch_step, c, x))
-    return run.lower(carry0, xs).compile()
+    compiled = _scan_program(batch_step).lower(carry0, xs).compile()
+    trace_lib.register_program(compiled)
+    return compiled
 
 
 class PublishEvent(NamedTuple):
@@ -458,92 +483,107 @@ def run_stream_device(users: np.ndarray, items: np.ndarray, cfg,
     n_segments = int(np.ceil(steps / seg))
     steps_padded = max(n_segments, 1) * seg
 
-    fu = np.full((steps_padded, mb), -1, np.int64)
-    fi = np.full((steps_padded, mb), -1, np.int64)
-    flat_u = fu[:n_batches].reshape(-1)
-    flat_i = fi[:n_batches].reshape(-1)
-    flat_u[:n] = users
-    flat_i[:n] = items
+    # Host spans: inside a session's ``ingest`` span these read
+    # ``ingest/prepare``, ``ingest/scan`` (with ``ingest/scan/boundary``
+    # per mid-call publish) and ``ingest/readback``.
+    with trace_lib.span("prepare"):
+        fu = np.full((steps_padded, mb), -1, np.int64)
+        fi = np.full((steps_padded, mb), -1, np.int64)
+        flat_u = fu[:n_batches].reshape(-1)
+        flat_i = fi[:n_batches].reshape(-1)
+        flat_u[:n] = users
+        flat_i[:n] = items
 
-    carry0 = init_scan_carry(cfg, states=initial_states, carry=initial_carry,
-                             detector=initial_detector)
-    xs = (jnp.asarray(fu, jnp.int32), jnp.asarray(fi, jnp.int32))
+        carry0 = init_scan_carry(cfg, states=initial_states,
+                                 carry=initial_carry,
+                                 detector=initial_detector)
+        xs = (jnp.asarray(fu, jnp.int32), jnp.asarray(fi, jnp.int32))
 
-    # AOT-compile so the wall clock measures steady-state streaming, not
-    # tracing (the host path warms its jit before its timer for the same
-    # reason). Memoized on the frozen config so benchmark repeats reuse
-    # the executable; mesh objects are unhashable, so explicit-mesh
-    # shard_map runs compile per call.
-    if mesh is None and cfg.backend != "shard_map":
-        compiled = compiled_scan(cfg, seg)
-    else:
-        worker_fn = _resolve_worker_fn(cfg, mesh=mesh)
-        batch_step, _, _ = _make_batch_step(cfg, worker_fn)
-        run = jax.jit(lambda c, x: jax.lax.scan(batch_step, c, x))
-        xs_seg = jax.tree.map(lambda x: x[:seg], xs)
-        compiled = run.lower(carry0, xs_seg).compile()
+        # AOT-compile so the wall clock measures steady-state streaming,
+        # not tracing (the host path warms its jit before its timer for
+        # the same reason). Memoized on the frozen config so benchmark
+        # repeats reuse the executable; mesh objects are unhashable, so
+        # explicit-mesh shard_map runs compile per call.
+        if mesh is None and cfg.backend != "shard_map":
+            compiled = compiled_scan(cfg, seg)
+        else:
+            worker_fn = _resolve_worker_fn(cfg, mesh=mesh)
+            batch_step, _, _ = _make_batch_step(cfg, worker_fn)
+            xs_seg = jax.tree.map(lambda x: x[:seg], xs)
+            compiled = _scan_program(batch_step).lower(carry0,
+                                                       xs_seg).compile()
+            trace_lib.register_program(compiled)
 
     t0 = time.perf_counter()
     publish_time = 0.0
     carry = carry0
     seg_outs = []
-    for s in range(max(n_segments, 1)):
-        xs_seg = jax.tree.map(lambda x: x[s * seg:(s + 1) * seg], xs)
-        carry, outs = compiled(carry, xs_seg)
-        seg_outs.append(outs)
-        if on_publish is not None:
-            # Publish boundary. Sync mode: read the progress scalars
-            # (states stay on device) and hand the immutable state tree
-            # to the subscriber. The scalar reads block until the
-            # segment's (async-dispatched) compute finishes — they must
-            # complete BEFORE the publish timer starts, or segment
-            # compute would be misattributed to the subscriber. Only
-            # subscriber work (e.g. a serving burst) is excluded from the
-            # training wall clock, keeping throughput comparable to
-            # non-publishing runs. Async mode (publish_sync=False): hand
-            # the un-synced device scalars over and keep dispatching —
-            # the subscriber thread pays the sync instead of this loop.
-            ev = PublishEvent(
-                states=carry[0],
-                events_processed=int(carry[4]) if publish_sync else carry[4],
-                dropped=int(carry[5]) if publish_sync else carry[5],
-                forgets=int(carry[6]) if publish_sync else carry[6],
-                segment=s,
-                steps_done=(s + 1) * seg,
-                detector=carry[7] if _adaptive(cfg) else None,
-                telemetry=carry[9] if cfg.telemetry else None,
-            )
-            tp = time.perf_counter()
-            on_publish(ev)
-            publish_time += time.perf_counter() - tp
-    states, cu, ci, _, processed, dropped, forgets, det, _, tel = carry
-    jax.block_until_ready(states)
+    with trace_lib.span("scan"):
+        for s in range(max(n_segments, 1)):
+            xs_seg = jax.tree.map(lambda x: x[s * seg:(s + 1) * seg], xs)
+            carry, outs = compiled(carry, xs_seg)
+            seg_outs.append(outs)
+            if on_publish is not None:
+                # Publish boundary. Sync mode: read the progress scalars
+                # (states stay on device) and hand the immutable state
+                # tree to the subscriber. The scalar reads block until the
+                # segment's (async-dispatched) compute finishes — they
+                # must complete BEFORE the publish timer starts, or
+                # segment compute would be misattributed to the
+                # subscriber. Only subscriber work (e.g. a serving burst)
+                # is excluded from the training wall clock, keeping
+                # throughput comparable to non-publishing runs. Async mode
+                # (publish_sync=False): hand the un-synced device scalars
+                # over and keep dispatching — the subscriber thread pays
+                # the sync instead of this loop.
+                ev = PublishEvent(
+                    states=carry[0],
+                    events_processed=(int(carry[4]) if publish_sync
+                                      else carry[4]),
+                    dropped=int(carry[5]) if publish_sync else carry[5],
+                    forgets=int(carry[6]) if publish_sync else carry[6],
+                    segment=s,
+                    steps_done=(s + 1) * seg,
+                    detector=carry[7] if _adaptive(cfg) else None,
+                    telemetry=carry[9] if cfg.telemetry else None,
+                )
+                tp = time.perf_counter()
+                with trace_lib.span("boundary"):
+                    on_publish(ev)
+                publish_time += time.perf_counter() - tp
+        states, cu, ci, _, processed, dropped, forgets, det, _, tel = carry
+        jax.block_until_ready(states)
     wall = time.perf_counter() - t0 - publish_time
 
-    bits, loads, kept_n, fired, u_occ, i_occ = (
-        np.concatenate([np.asarray(o[j]) for o in seg_outs])
-        for j in range(6)
-    )
-    processed = int(processed)
-    dropped = int(dropped) + int(np.sum(np.asarray(cu) >= 0))
+    with trace_lib.span("readback"):
+        bits, loads, kept_n, fired, u_occ, i_occ = (
+            np.concatenate([np.asarray(o[j]) for o in seg_outs])
+            for j in range(6)
+        )
+        processed = int(processed)
+        dropped = int(dropped) + int(np.sum(np.asarray(cu) >= 0))
 
-    acc = RecallAccumulator()
-    active = [s for s in range(bits.shape[0])
-              if loads[s].sum() > 0 or s < n_batches]
-    for s in active:
-        acc.add_raw(bits[s])
-    load_history = [loads[s] for s in active]
-    drift_flags = (np.asarray([fired[s] for s in active], np.int32)
-                   if _adaptive(cfg) else None)
+        acc = RecallAccumulator()
+        active = [s for s in range(bits.shape[0])
+                  if loads[s].sum() > 0 or s < n_batches]
+        for s in active:
+            acc.add_raw(bits[s])
+        load_history = [loads[s] for s in active]
+        drift_flags = (np.asarray([fired[s] for s in active], np.int32)
+                       if _adaptive(cfg) else None)
 
-    cum = np.cumsum(kept_n)
-    user_occ, item_occ = [], []
-    for j, s in enumerate(active):
-        if j % cfg.record_every == 0 or j == len(active) - 1:
-            user_occ.append((int(cum[s]), u_occ[s]))
-            item_occ.append((int(cum[s]), i_occ[s]))
-        if verbose and j % 16 == 0:
-            print(f"[engine] step {j}/{len(active)}")
+        cum = np.cumsum(kept_n)
+        user_occ, item_occ = [], []
+        for j, s in enumerate(active):
+            if j % cfg.record_every == 0 or j == len(active) - 1:
+                user_occ.append((int(cum[s]), u_occ[s]))
+                item_occ.append((int(cum[s]), i_occ[s]))
+            if verbose and j % 16 == 0:
+                print(f"[engine] step {j}/{len(active)}")
+        final_detector = (jax.tree.map(np.asarray, det) if _adaptive(cfg)
+                          else None)
+        telemetry = (jax.tree.map(np.asarray, tel) if cfg.telemetry
+                     else None)
 
     return StreamResult(
         recall=acc,
@@ -556,7 +596,6 @@ def run_stream_device(users: np.ndarray, items: np.ndarray, cfg,
         final_states=states,
         forgets=int(forgets),
         drift_flags=drift_flags,
-        final_detector=(jax.tree.map(np.asarray, det) if _adaptive(cfg)
-                        else None),
-        telemetry=(jax.tree.map(np.asarray, tel) if cfg.telemetry else None),
+        final_detector=final_detector,
+        telemetry=telemetry,
     )

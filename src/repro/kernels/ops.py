@@ -27,6 +27,7 @@ from repro.kernels.factor_update import factor_update_pallas
 from repro.kernels.scoring import masked_scores_pallas
 from repro.kernels.swa_attention import swa_attention_pallas
 from repro.kernels.topn import QUERY_BLOCK, dics_topn_pallas, fused_topn_pallas
+from repro.obs import trace as trace_lib
 
 __all__ = ["on_tpu", "masked_scores", "clear_columns", "factor_update",
            "dics_update", "fused_topn", "dics_topn", "swa_attention",
@@ -143,7 +144,8 @@ def _clear_columns_batched(table, cols, clear):
                                                    (w, 0, cols[w, e])),
             lambda r: r, r)
 
-    return (jax.lax.fori_loop(0, n_w * n_e, clear_one, table),)
+    with trace_lib.layer("column_clear"):
+        return (jax.lax.fori_loop(0, n_w * n_e, clear_one, table),)
 
 
 clear_columns = _worker_batched(_clear_columns_batched, 1)
@@ -177,32 +179,34 @@ def factor_update(user_vecs, item_vecs, rated, tabs, events, *, eta: float,
     in order, and the rows are scattered back in place. Returns
     ``(user_vecs, item_vecs, rated, tabs)``.
     """
-    if interpret is None:
-        if not on_tpu():
-            return ref.factor_apply(
-                user_vecs, item_vecs, rated, tabs, events, eta=eta, lam=lam)
-        interpret = False
-    ev_u, ev_i, u_slots, i_slots, j_slots, init_u, init_i = events
-    pairwise = j_slots is not None
-    if not pairwise:
-        j_slots = i_slots
-    plan = bookkeeping.plan_bucket(tuple(tabs), ev_u, ev_i, u_slots, i_slots,
-                                   j_slots if pairwise else None)
-    e = ev_u.shape[0]
-    u_rows, ua, u_dest = _compact_rows(user_vecs, u_slots)
-    cat = jnp.concatenate([i_slots, j_slots])
-    i_rows, cat_first, i_dest = _compact_rows(item_vecs, cat)
-    r_rows = rated[u_slots].astype(jnp.float32)
-    scalars = (ua, cat_first[:e], cat_first[e:], i_slots.astype(jnp.int32),
-               j_slots.astype(jnp.int32), plan.flags)
-    kernel = _factor_kernel(eta, lam, pairwise, interpret)
-    u_rows, i_rows, r_rows = kernel(*scalars, init_u, init_i, u_rows, i_rows,
-                                    r_rows)
-    (rated,) = _write_rated(rated, i_slots, (ev_u >= 0) & plan.new_i, u_dest,
-                            r_rows != 0)
-    user_vecs = user_vecs.at[u_dest].set(u_rows, mode="drop")
-    item_vecs = item_vecs.at[i_dest].set(i_rows, mode="drop")
-    return user_vecs, item_vecs, rated, plan.tables
+    with trace_lib.layer("update"):
+        if interpret is None:
+            if not on_tpu():
+                return ref.factor_apply(user_vecs, item_vecs, rated, tabs,
+                                        events, eta=eta, lam=lam)
+            interpret = False
+        ev_u, ev_i, u_slots, i_slots, j_slots, init_u, init_i = events
+        pairwise = j_slots is not None
+        if not pairwise:
+            j_slots = i_slots
+        plan = bookkeeping.plan_bucket(tuple(tabs), ev_u, ev_i, u_slots,
+                                       i_slots, j_slots if pairwise else None)
+        e = ev_u.shape[0]
+        u_rows, ua, u_dest = _compact_rows(user_vecs, u_slots)
+        cat = jnp.concatenate([i_slots, j_slots])
+        i_rows, cat_first, i_dest = _compact_rows(item_vecs, cat)
+        r_rows = rated[u_slots].astype(jnp.float32)
+        scalars = (ua, cat_first[:e], cat_first[e:],
+                   i_slots.astype(jnp.int32), j_slots.astype(jnp.int32),
+                   plan.flags)
+        kernel = _factor_kernel(eta, lam, pairwise, interpret)
+        u_rows, i_rows, r_rows = kernel(*scalars, init_u, init_i, u_rows,
+                                        i_rows, r_rows)
+        (rated,) = _write_rated(rated, i_slots, (ev_u >= 0) & plan.new_i,
+                                u_dest, r_rows != 0)
+        user_vecs = user_vecs.at[u_dest].set(u_rows, mode="drop")
+        item_vecs = item_vecs.at[i_dest].set(i_rows, mode="drop")
+        return user_vecs, item_vecs, rated, plan.tables
 
 
 def dics_update(co, item_cnt, rated, tabs, events, *,
@@ -217,22 +221,23 @@ def dics_update(co, item_cnt, rated, tabs, events, *,
     bookkeeping come from ``bookkeeping.plan_bucket``, and the touched
     rated rows travel as compact blocks like in :func:`factor_update`.
     """
-    if interpret is None:
-        if not on_tpu():
-            return ref.dics_apply(co, item_cnt, rated, tabs, events)
-        interpret = False
-    ev_u, ev_i, u_slots, i_slots = events
-    plan = bookkeeping.plan_bucket(tuple(tabs), ev_u, ev_i, u_slots, i_slots,
-                                   item_cnt=item_cnt)
-    i_cap = co.shape[0]
-    r_rows, ua, u_dest = _compact_rows(rated, u_slots)
-    r_rows = _pad_to(r_rows.astype(jnp.float32), 1, 128)
-    co_p = _pad_to(_pad_to(co, 0, 128), 1, 128)
-    scalars = (ua, i_slots.astype(jnp.int32), plan.flags)
-    r_rows, co_p = _dics_kernel(interpret)(*scalars, r_rows, co_p)
-    (rated,) = _write_rated(rated, i_slots, plan.new_i, u_dest,
-                            r_rows[:, :i_cap] != 0)
-    return co_p[:i_cap, :i_cap], plan.item_cnt, rated, plan.tables
+    with trace_lib.layer("update"):
+        if interpret is None:
+            if not on_tpu():
+                return ref.dics_apply(co, item_cnt, rated, tabs, events)
+            interpret = False
+        ev_u, ev_i, u_slots, i_slots = events
+        plan = bookkeeping.plan_bucket(tuple(tabs), ev_u, ev_i, u_slots,
+                                       i_slots, item_cnt=item_cnt)
+        i_cap = co.shape[0]
+        r_rows, ua, u_dest = _compact_rows(rated, u_slots)
+        r_rows = _pad_to(r_rows.astype(jnp.float32), 1, 128)
+        co_p = _pad_to(_pad_to(co, 0, 128), 1, 128)
+        scalars = (ua, i_slots.astype(jnp.int32), plan.flags)
+        r_rows, co_p = _dics_kernel(interpret)(*scalars, r_rows, co_p)
+        (rated,) = _write_rated(rated, i_slots, plan.new_i, u_dest,
+                                r_rows[:, :i_cap] != 0)
+        return co_p[:i_cap, :i_cap], plan.item_cnt, rated, plan.tables
 
 
 def fused_topn(u_vecs, item_vecs, mask, item_ids, *, top_n: int,

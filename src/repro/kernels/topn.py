@@ -141,6 +141,7 @@ def fused_topn_pallas(u_vecs, item_vecs, mask_i8, ids_row, *, top_n: int,
             pltpu.VMEM((block_b, top_n), jnp.int32),
         ],
         interpret=interpret,
+        name="fused_topn",
     )(u_vecs, item_vecs, mask_i8, ids_row)
     return out_id, out_sc
 
@@ -269,5 +270,6 @@ def dics_topn_pallas(co, item_cnt_row, hist_i8, known_i32, ids_row, *,
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=int(
             min(max(32 * 2**20, 12 * block_p * i * 4), 100 * 2**20))),
         interpret=interpret,
+        name="dics_topn",
     )(co, item_cnt_row, item_cnt_row, hist_i8, hist_i8, known_i32, ids_row)
     return out_id, out_sc

@@ -56,6 +56,7 @@ import numpy as np
 
 from repro.core import state as state_lib
 from repro.obs import metrics as metrics_lib
+from repro.obs import trace as trace_lib
 
 __all__ = ["Snapshot", "SnapshotStore", "StaleSnapshotError",
            "popularity_topn"]
@@ -249,9 +250,12 @@ class SnapshotStore:
                 # int() here is THE deferred host sync of the non-blocking
                 # publish boundary — it runs on this thread, so the scan
                 # never waited for it. Same for the telemetry fold below.
-                self._rotate(states, int(events), int(forgets), mode="async")
-                if telemetry is not None and self._telemetry_sink is not None:
-                    self._telemetry_sink(telemetry)
+                with trace_lib.span("publish_async", self.metrics):
+                    self._rotate(states, int(events), int(forgets),
+                                 mode="async")
+                    if (telemetry is not None
+                            and self._telemetry_sink is not None):
+                        self._telemetry_sink(telemetry)
         except BaseException:
             # A failing rotation (e.g. a raising listener) must not wedge
             # the store: reopen the spawn gate so the next enqueue

@@ -180,23 +180,28 @@ class StreamSession:
             self._detector = res.final_detector
         self.events_processed += res.events_processed
         self.forgets += res.forgets
-        self._publish()
-        # Final fold: the end-of-run vector covers any tail past the last
-        # publish boundary (or the whole run when publishing was off).
-        # After _publish's flush, no async fold is in flight.
-        self._telemetry.fold(res.telemetry)
+        self._publish(telemetry=res.telemetry)
         return res
 
-    def _publish(self) -> None:
+    def _publish(self, telemetry=None) -> None:
         # Drain in-flight async rotations first: a mid-stream buffer
         # rotating after this final sync publish would regress the front
         # snapshot to an older stream position, breaking the "recommend
         # right after ingest sees the final state" guarantee.
         with trace_lib.span("publish", self.metrics):
-            self.store.flush()
-            self.store.publish(self._states, self.events_processed,
-                               self.forgets)
+            with trace_lib.span("flush"):
+                self.store.flush()
+            with trace_lib.span("rotate"):
+                self.store.publish(self._states, self.events_processed,
+                                   self.forgets)
             self._update_table_bytes()
+            # Final fold of an ingest: the end-of-run vector covers any
+            # tail past the last publish boundary (or the whole run when
+            # publishing was off). After the flush, no async fold is in
+            # flight.
+            if telemetry is not None:
+                with trace_lib.span("fold"):
+                    self._telemetry.fold(telemetry)
 
     # -- serve ------------------------------------------------------------
 

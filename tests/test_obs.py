@@ -387,3 +387,101 @@ def test_session_verbs_record_spans():
     stages = {labels["stage"]
               for labels, _ in s.metrics.get("span_seconds").series()}
     assert {"ingest", "publish", "serve", "regrid"} <= stages
+
+
+def _stages(reg):
+    fam = reg.get("span_seconds")
+    return {labels["stage"] for labels, _ in fam.series()} if fam else set()
+
+
+def test_nested_span_records_into_its_parents_registry():
+    reg, own = MetricsRegistry(), MetricsRegistry()
+    with span("ingest", reg):
+        with span("scan") as path:
+            assert path == "ingest/scan"
+            with span("boundary"):
+                pass
+        with span("readback", own):
+            with span("fold"):
+                pass
+    with span("alone"):
+        pass
+    assert _stages(reg) == {"ingest", "ingest/scan", "ingest/scan/boundary"}
+    # A span given a registry records there, and so do its children.
+    assert _stages(own) == {"ingest/readback", "ingest/readback/fold"}
+
+
+@pytest.mark.parametrize("mode", ["sync", "async"])
+def test_session_ingest_records_engine_and_publish_spans(mode, monkeypatch):
+    import collections
+
+    from repro.core import engine
+    from repro.obs import trace
+
+    monkeypatch.setattr(trace, "_programs", collections.deque(maxlen=16))
+    engine.compiled_scan.cache_clear()
+    users, items = _stream(n=1200)
+    s = repro.StreamSession(_cfg(backend="scan"),
+                            publish=repro.PublishPolicy(every=2, mode=mode))
+    s.ingest(users, items)
+    stages = _stages(s.metrics)
+    assert {"ingest", "ingest/prepare", "ingest/scan",
+            "ingest/scan/boundary", "ingest/readback", "publish",
+            "publish/flush", "publish/rotate", "publish/fold"} <= stages
+    # The publisher thread's rotations land in the store's registry.
+    assert ("publish_async" in stages) == (mode == "async")
+    # The scan program the call ran is registered and mapped.
+    assert "route" in trace.op_layers()["jit_engine_scan"].values()
+
+
+def test_layer_accepts_only_the_engines_layers():
+    from repro.obs import trace
+
+    assert trace.LAYERS == ("route", "score", "update", "column_clear",
+                            "telemetry", "forget")
+    with pytest.raises(ValueError, match="unknown layer"):
+        trace.layer("scoring")
+
+
+_HLO = """HloModule jit_fake_scan, entry_computation_layout={(f32[8]{0})->f32[8]{0}}
+
+%fused_computation (param_0: f32[8]) -> f32[8] {
+  %param_0 = f32[8]{0} parameter(0)
+  ROOT %negate.0 = f32[8]{0} negate(%param_0), metadata={op_name="jit(engine_scan)/update/neg"}
+}
+
+ENTRY %main (p: f32[8]) -> f32[8] {
+  %p = f32[8]{0} parameter(0)
+  %sort.2 = f32[8]{0} sort(%p), dimensions={0}, metadata={op_name="jit(engine_scan)/while/body/vmap(score)/top_k" source_file="x.py" source_line=3}
+  %copy.1 = f32[8]{0} copy(%sort.2)
+  %copy.3 = f32[8]{0} copy(%p)
+  %while.11 = f32[8]{0} while(%copy.3), condition=%c, body=%b, metadata={op_name="jit(engine_scan)/vmap(update)/column_clear/while"}
+  %fusion.4 = f32[8]{0} fusion(%copy.1, %while.11), kind=kLoop, calls=%fused_computation, metadata={op_name="update/reduce_sum"}
+  %add.5 = f32[8]{0} add(%fusion.4, %p), metadata={op_name="jit(engine_scan)/jit(update)/add"}
+  ROOT %add.6 = f32[8]{0} add(%add.5, %add.5)
+}
+"""
+
+
+def test_op_layers_maps_instructions_to_their_innermost_layer(monkeypatch):
+    import collections
+    from types import SimpleNamespace
+
+    from repro.obs import trace
+
+    monkeypatch.setattr(trace, "_programs", collections.deque(maxlen=16))
+    trace.register_program(SimpleNamespace(as_text=lambda: _HLO))
+    got = trace.op_layers()["jit_fake_scan"]
+    assert got["sort.2"] == "score"              # through vmap(...)
+    assert got["while.11"] == "column_clear"     # innermost of two
+    assert got["fusion.4"] == "update"           # a shortened path
+    assert got["negate.0"] == "update"
+    assert got["add.5"] == ""                    # jit(update) is a function
+    # No metadata: the layer its users share, else none.
+    assert got["copy.3"] == "column_clear" and got["copy.1"] == "update"
+    assert got["p"] == "" and got["add.6"] == ""
+    # Two programs of one module that disagree leave the op unscoped.
+    trace.register_program(SimpleNamespace(
+        as_text=lambda: _HLO.replace("vmap(score)", "route")))
+    got = trace.op_layers()["jit_fake_scan"]
+    assert got["sort.2"] == "" and got["while.11"] == "column_clear"

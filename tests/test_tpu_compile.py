@@ -142,3 +142,48 @@ def test_engine_pallas_worker_compiles_for_16_workers(one_chip, monkeypatch,
     # States are donated, as the engine's scan carry updates them in place.
     text = _compiled_text(worker, one_chip, states, ev, ev, donate=(0,))
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("algorithm", ["disgd", "dics"])
+def test_engine_scan_names_its_layers(one_chip, monkeypatch, algorithm):
+    """The engine's scan program, compiled for the described v5e, keeps
+    the layer scopes in its instructions' metadata: ``trace.op_layers``
+    places the update kernel in ``update``, the bucket-start top-k sort
+    in ``score`` and the column-clear loop in ``column_clear``."""
+    import collections
+    import re
+
+    from repro.core import algorithm as algorithm_lib
+    from repro.obs import trace
+
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    # Only this program: others of the same module name would merge in.
+    monkeypatch.setattr(trace, "_programs", collections.deque(maxlen=16))
+    algo = algorithm_lib.get_algorithm(algorithm)
+    hyper = algo.default_hyper()._replace(u_cap=U_CAP, i_cap=I_CAP)
+    cfg = StreamConfig(algorithm=algorithm, grid=GridSpec(4),
+                       micro_batch=MB, capacity_factor=1.5, hyper=hyper,
+                       backend="pallas")
+    # The program compiled_scan builds, with its shapes on the described
+    # chip.
+    batch_step, _, _ = engine._make_batch_step(
+        cfg, engine.make_pallas_worker_fn(cfg))
+    shapes = (jax.eval_shape(lambda: engine.init_scan_carry(cfg)),
+              (_s((2, MB), jnp.int32),) * 2)
+    compiled = engine._scan_program(batch_step).lower(*jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        shapes)).compile()
+    trace.register_program(compiled)
+    text = compiled.as_text()
+    assert text.startswith("HloModule jit_engine_scan")
+    layers = trace.op_layers()["jit_engine_scan"]
+    ops_of = {}
+    for name, kind in re.findall(
+            r"^\s*(?:ROOT )?%([\w.\-]+) = [^\n]*? ([a-z][\w\-]*)\(", text,
+            re.M):
+        ops_of.setdefault(kind, []).append(name)
+    kernel = f"{algorithm}_update" if algorithm == "dics" else "factor_update"
+    kernels = [n for n in ops_of["custom-call"] if n.startswith(kernel)]
+    assert kernels and {layers[n] for n in kernels} == {"update"}
+    assert "score" in {layers[n] for n in ops_of["sort"]}
+    assert "column_clear" in {layers[n] for n in ops_of["while"]}
