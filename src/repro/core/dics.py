@@ -219,20 +219,53 @@ def dics_worker_step(state: DicsState, events, hyper: DicsHyper):
     return state, hits, evaluated
 
 
+def _bucket_topn(st: DicsState, rated_rows, known_u, hyper: DicsHyper):
+    """Each bucket event's top-N (global item ids, scores) against the
+    bucket-start statistics: the fused kernel on TPU, else the jnp
+    oracle (Eq. 6 once per bucket, Eq. 7 batched in chunks that bound
+    the [E, I, I] intermediate, then ``lax.top_k``)."""
+    t = st.tables
+    if ops.on_tpu():
+        return ops.dics_topn(st.co, st.item_cnt, rated_rows, known_u,
+                             t.item_ids, top_n=hyper.top_n, k_nn=hyper.k_nn)
+    sim = similarity_matrix(st.co, st.item_cnt)       # [I, I]
+
+    def score_chunk(rows):
+        return jax.vmap(lambda r: dics_scores(
+            st.co, st.item_cnt, r, t.item_ids, hyper.k_nn, sim=sim))(rows)
+
+    n_ev, i_cap = rated_rows.shape
+    chunk = max(1, min(n_ev, (1 << 22) // max(1, i_cap * i_cap)))
+    while n_ev % chunk:
+        chunk -= 1
+    scores = jax.lax.map(
+        score_chunk, rated_rows.reshape(n_ev // chunk, chunk, i_cap)
+    ).reshape(n_ev, i_cap)
+    top_scores, top_idx = jax.lax.top_k(
+        scores, min(hyper.top_n, scores.shape[-1]))
+    return t.item_ids[top_idx], top_scores
+
+
 def make_pallas_worker(hyper: DicsHyper):
     """DICS worker step built on the fused kernels (fast path).
 
     The reference step rebuilds the full [I, I] similarity matrix from
     scratch INSIDE the per-event scan — O(I^2) work per event — because
     the co/cnt statistics change under it as the bucket proceeds. The
-    fast path hoists Eq. 6 to once per bucket: all events score against
-    the bucket-start statistics (batched, chunked to bound the [E, I, I]
-    intermediate), then the fused sequential update op
-    (``ops.dics_update`` -> ``kernels/dics_update.py``) applies the
-    co-count scatters event-for-event — final states are EXACT against
+    fast path scores all events of the bucket against the bucket-start
+    statistics, then the fused sequential update op (``ops.dics_update``
+    -> ``kernels/dics_update.py``) applies the co-count scatters
+    event-for-event — final states are EXACT against
     ``dics_worker_step``, unguarded eviction clears included; recall
     bits carry the same bucket-start tolerance contract as the factor
     fast paths.
+
+    On TPU the bucket-start scoring is the serve leaf's fused kernel
+    (``ops.dics_topn``): Eq. 6 tiles, the top-k_nn neighbour mass and
+    the top-N list in VMEM, without the [E, I, I] neighbourhoods or a
+    sort. Elsewhere the jnp path of ``_bucket_topn`` is the oracle. The
+    two agree on every hit whose top-N decision is not a tie (the
+    kernel breaks ties by global id, ``lax.top_k`` by slot).
     """
     u_cap, i_cap = hyper.u_cap, hyper.i_cap
 
@@ -245,27 +278,13 @@ def make_pallas_worker(hyper: DicsHyper):
             i_slot = state_lib.slot_of(ev_i, hyper.n_i, i_cap)
             known_u = t.user_ids[u_slot] == ev_u
             known_i = t.item_ids[i_slot] == ev_i
-
-            # --- recommend (Eq. 6 once per bucket, Eq. 7 batched) ---
-            sim = similarity_matrix(st.co, st.item_cnt)       # [I, I]
             rated_rows = st.rated[u_slot] & known_u[:, None]  # [E, I]
 
-            def score_chunk(rows):
-                return jax.vmap(lambda r: dics_scores(
-                    st.co, st.item_cnt, r, t.item_ids, hyper.k_nn,
-                    sim=sim))(rows)
-
-            n_ev = ev_u.shape[0]
-            chunk = max(1, min(n_ev, (1 << 22) // max(1, i_cap * i_cap)))
-            while n_ev % chunk:
-                chunk -= 1
-            scores = jax.lax.map(
-                score_chunk, rated_rows.reshape(n_ev // chunk, chunk, i_cap)
-            ).reshape(n_ev, i_cap)
-            top_scores, top_idx = jax.lax.top_k(
-                scores, min(hyper.top_n, scores.shape[-1]))
+            # --- recommend (bucket-start Eq. 6/7 top-N) ---
+            top_ids, top_scores = _bucket_topn(st, rated_rows, known_u,
+                                               hyper)
             hits = jnp.any(
-                (t.item_ids[top_idx] == ev_i[:, None])
+                (top_ids == ev_i[:, None])
                 & jnp.isfinite(top_scores) & (top_scores > 0),
                 axis=-1,
             ) & valid & known_i

@@ -26,7 +26,7 @@ from repro.kernels.dics_update import dics_update_pallas
 from repro.kernels.factor_update import factor_update_pallas
 from repro.kernels.scoring import masked_scores_pallas
 from repro.kernels.swa_attention import swa_attention_pallas
-from repro.kernels.topn import QUERY_BLOCK, dics_topn_pallas, fused_topn_pallas
+from repro.kernels.topn import dics_topn_pallas, fused_topn_pallas, query_block
 from repro.obs import trace as trace_lib
 
 __all__ = ["on_tpu", "masked_scores", "clear_columns", "factor_update",
@@ -281,13 +281,14 @@ def fused_topn(u_vecs, item_vecs, mask, item_ids, *, top_n: int,
 
 def dics_topn(co, item_cnt, hist, known, item_ids, *, top_n: int,
               k_nn: int, block_p: int = 128, interpret: bool | None = None):
-    """DICS Eq. 6/7 serve leaf kernel (similarity + neighbor mass +
-    partial top-N in one pass).
+    """DICS Eq. 6/7 kernel (similarity + neighbor mass + partial top-N
+    in one pass): the serve leaf and training's bucket-start scoring.
 
-    Unlike the other ops this has no oracle shortcut — the jnp path
-    lives in ``core/dics.dics_partial_topn``, which is also the dispatch
-    site; ``interpret=None`` runs the interpret-mode kernel off TPU so
-    the body stays exercisable everywhere.
+    Unlike the other ops this has no oracle shortcut — the jnp paths
+    live in ``core/dics`` (``dics_partial_topn``, ``_bucket_topn``),
+    which are also the dispatch sites; ``interpret=None`` runs the
+    interpret-mode kernel off TPU so the body stays exercisable
+    everywhere.
 
     Args:
       co: f32[I, I]; item_cnt: f32[I]; hist: bool[B, I] known-masked
@@ -304,15 +305,15 @@ def dics_topn(co, item_cnt, hist, known, item_ids, *, top_n: int,
     cntp = _pad_to(item_cnt.reshape(1, -1), 1, block_p)
     # Queries come in sublane-aligned blocks; padded rows are unknown
     # users and are cut off below.
-    histp = _pad_to(_pad_to(hist.astype(jnp.int8), 1, block_p), 0,
-                    QUERY_BLOCK)
-    knownp = _pad_to(known.astype(jnp.int32).reshape(-1, 1), 0, QUERY_BLOCK)
+    block_q = query_block(b)
+    histp = _pad_to(_pad_to(hist.astype(jnp.int8), 1, block_p), 0, block_q)
+    knownp = _pad_to(known.astype(jnp.int32).reshape(-1, 1), 0, block_q)
     # Padded candidates carry cnt 0 -> zero neighbor mass -> excluded by
     # the score > 0 rule; id INT32_MAX keeps them after every real entry.
     idp = _pad_to(item_ids.reshape(1, -1), 1, block_p, value=_I32_MAX)
     out_id, out_sc = dics_topn_pallas(
         cop, cntp, histp, knownp, idp.astype(jnp.int32), top_n=top_n,
-        k_nn=k_nn, block_p=block_p, interpret=interpret,
+        k_nn=k_nn, block_p=block_p, block_q=block_q, interpret=interpret,
     )
     return out_id[:b], out_sc[:b]
 

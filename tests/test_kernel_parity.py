@@ -28,11 +28,12 @@ import pytest
 
 from repro.algos.bpr import BprHyper
 from repro.core import state as state_lib
-from repro.core.dics import DicsHyper, dics_partial_topn
+from repro.core.dics import DicsHyper, dics_partial_topn, dics_scores
 from repro.core.disgd import DisgdHyper
 from repro.core.forgetting import ForgettingConfig
 from repro.core.pipeline import StreamConfig, run_stream
 from repro.core.routing import GridSpec
+from repro.core.state import DicsState, Tables
 from repro.drift.controller import DriftPolicy
 from repro.kernels import ops, ref
 
@@ -249,6 +250,122 @@ def test_dics_topn_kernel_matches_jnp_leaf():
     np.testing.assert_array_equal(np.asarray(got_ids), np.asarray(want_ids))
     np.testing.assert_allclose(np.asarray(got_sc), np.asarray(want_sc),
                                rtol=1e-5, atol=1e-6)
+
+
+def _warm_dics_worker(rng, u_cap, i_cap):
+    """A DICS worker state with co-counts from its rated bits: small
+    integer counts (so similarities tie), empty user and item slots, and
+    users whose histories run from none to most of the items."""
+    uid = np.arange(u_cap) + u_cap * rng.integers(0, 4, u_cap)
+    iid = np.arange(i_cap) + i_cap * rng.integers(0, 4, i_cap)
+    uid[rng.random(u_cap) < 0.1] = -1
+    iid[rng.random(i_cap) < 0.15] = -1
+    density = rng.choice([0.0, 0.05, 0.1, 0.5, 0.9], size=u_cap)
+    rated = ((rng.random((u_cap, i_cap)) < density[:, None])
+             & (uid >= 0)[:, None] & (iid >= 0)[None, :])
+    r = rated.astype(np.float32)
+    clock = 200
+    tabs = Tables(
+        jnp.asarray(uid, jnp.int32), jnp.asarray(iid, jnp.int32),
+        jnp.asarray(rated.sum(1), jnp.int32),
+        jnp.asarray(rated.sum(0), jnp.int32),
+        jnp.asarray(rng.integers(0, clock, u_cap), jnp.int32),
+        jnp.asarray(rng.integers(0, clock, i_cap), jnp.int32),
+        jnp.asarray(clock, jnp.int32))
+    return DicsState(tables=tabs, co=jnp.asarray(r.T @ r),
+                     item_cnt=jnp.asarray(r.sum(0)),
+                     rated=jnp.asarray(rated))
+
+
+def _dics_bucket(rng, st, e, k_nn):
+    """``e`` events on ``st``'s worker: known and unknown users and items
+    (an unknown item evicts its slot's tenant), and padding. Most known
+    users rate an item ranked near the top-N boundary of their scores."""
+    uid = np.asarray(st.tables.user_ids)
+    iid = np.asarray(st.tables.item_ids)
+    u_cap, i_cap = uid.size, iid.size
+    us = rng.integers(0, u_cap, e)
+    scores = np.asarray(jax.vmap(lambda r: dics_scores(
+        st.co, st.item_cnt, r, st.tables.item_ids, k_nn))(st.rated[us]))
+    ranked = np.argsort(-scores, axis=1, kind="stable")
+    its = np.where(rng.random(e) < 0.7,
+                   ranked[np.arange(e), rng.integers(0, 16, e)],
+                   rng.integers(0, i_cap, e))
+    ev_u = np.where(uid[us] >= 0, uid[us], us)
+    ev_i = np.where(iid[its] >= 0, iid[its], its)
+    new_u, new_i = rng.random(e) < 0.1, rng.random(e) < 0.1
+    ev_u[new_u] = us[new_u] + 7 * u_cap
+    ev_i[new_i] = its[new_i] + 7 * i_cap
+    pad = rng.random(e) < 0.15
+    ev_u[pad], ev_i[pad] = -1, -1
+    return jnp.asarray(ev_u, jnp.int32), jnp.asarray(ev_i, jnp.int32)
+
+
+def _firm_hits(st, ev_u, ev_i, hyper, tol=1e-5):
+    """Events whose hit the bucket-start scores decide outside a tie at
+    the ``top_n``-th place (within ``tol``, relative, for float sums)."""
+    t = st.tables
+    u_slot = state_lib.slot_of(ev_u, hyper.g, hyper.u_cap)
+    i_slot = np.asarray(state_lib.slot_of(ev_i, hyper.n_i, hyper.i_cap))
+    known_u = t.user_ids[u_slot] == ev_u
+    rows = st.rated[u_slot] & known_u[:, None]
+    s = np.asarray(jax.vmap(lambda r: dics_scores(
+        st.co, st.item_cnt, r, t.item_ids, hyper.k_nn))(rows))
+    s = np.where(s > 0, s, -np.inf)
+    target = s[np.arange(s.shape[0]), i_slot][:, None]
+    above = np.sum(s > target * (1 + tol), axis=1)
+    level = np.sum(s >= target * (1 - tol), axis=1)
+    tied = np.isfinite(target[:, 0]) & (above < hyper.top_n) & (
+        level > hyper.top_n)
+    # Candidates sharing a score with another candidate of their row.
+    same = (s[:, :, None] == s[:, None, :]) & np.isfinite(s)[:, :, None]
+    return ~tied, int(np.sum(same.sum(-1) > 1))
+
+
+def test_dics_pallas_worker_kernel_scoring_matches_jnp_oracle(monkeypatch):
+    """The DICS Pallas worker step with its bucket-start scoring on the
+    fused kernel (interpret mode), vmapped over two workers as the engine
+    maps it, against the same step on the jnp oracle: the same hit bits
+    wherever the top-N decision is no tie, and identical states (the
+    update stays on its oracle on both sides)."""
+    from repro.core import dics
+
+    hyper = DicsHyper(k_nn=3, top_n=8, u_cap=24, i_cap=40)
+    rng = np.random.default_rng(7)
+    states = [_warm_dics_worker(rng, hyper.u_cap, hyper.i_cap)
+              for _ in range(2)]
+    buckets = [_dics_bucket(rng, st, 48, hyper.k_nn) for st in states]
+    stacked = jax.tree.map(lambda *x: jnp.stack(x), *states)
+    ev_u = jnp.stack([b[0] for b in buckets])
+    ev_i = jnp.stack([b[1] for b in buckets])
+    hist_len = np.asarray(jnp.sum(stacked.rated, axis=-1))
+    assert hist_len.min() < hyper.k_nn < hist_len.max()
+
+    def run():
+        step = jax.jit(jax.vmap(dics.make_pallas_worker(hyper)))
+        return step(stacked, (ev_u, ev_i))
+
+    want_st, want_hits, want_valid = run()
+    kernel_topn = ops.dics_topn
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    monkeypatch.setattr(ops, "dics_topn", lambda *a, **k: kernel_topn(
+        *a, **k, interpret=True))
+    monkeypatch.setattr(ops, "dics_update", lambda *a: ref.dics_apply(*a))
+    got_st, got_hits, got_valid = run()
+
+    for a, b in zip(jax.tree.leaves(got_st), jax.tree.leaves(want_st)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    np.testing.assert_array_equal(np.asarray(got_valid),
+                                  np.asarray(want_valid))
+    checked = hits = ties = 0
+    for w, st in enumerate(states):
+        firm, w_ties = _firm_hits(st, ev_u[w], ev_i[w], hyper)
+        got, want = np.asarray(got_hits[w]), np.asarray(want_hits[w])
+        np.testing.assert_array_equal(got[firm], want[firm])
+        checked += int(firm.sum())
+        hits += int(want[firm].sum())
+        ties += w_ties
+    assert checked >= 80 and hits > 0 and ties > 0
 
 
 # -- property test: fused partial-topn == score-then-select ---------------

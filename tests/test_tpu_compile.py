@@ -148,8 +148,10 @@ def test_engine_pallas_worker_compiles_for_16_workers(one_chip, monkeypatch,
 def test_engine_scan_names_its_layers(one_chip, monkeypatch, algorithm):
     """The engine's scan program, compiled for the described v5e, keeps
     the layer scopes in its instructions' metadata: ``trace.op_layers``
-    places the update kernel in ``update``, the bucket-start top-k sort
-    in ``score`` and the column-clear loop in ``column_clear``."""
+    places the update kernel in ``update``, the bucket-start scoring in
+    ``score`` (DISGD's top-k sort; DICS' fused selection kernel, with no
+    sort over the bucket's [I, I] neighbourhoods left) and the
+    column-clear loop in ``column_clear``."""
     import collections
     import re
 
@@ -177,13 +179,22 @@ def test_engine_scan_names_its_layers(one_chip, monkeypatch, algorithm):
     text = compiled.as_text()
     assert text.startswith("HloModule jit_engine_scan")
     layers = trace.op_layers()["jit_engine_scan"]
-    ops_of = {}
-    for name, kind in re.findall(
-            r"^\s*(?:ROOT )?%([\w.\-]+) = [^\n]*? ([a-z][\w\-]*)\(", text,
-            re.M):
-        ops_of.setdefault(kind, []).append(name)
+    ops_of, lines = {}, {}
+    for line in text.splitlines():
+        m = re.match(r"^\s*(?:ROOT )?%([\w.\-]+) = [^\n]*? ([a-z][\w\-]*)\(",
+                     line)
+        if m:
+            ops_of.setdefault(m[2], []).append(m[1])
+            lines[m[1]] = line
     kernel = f"{algorithm}_update" if algorithm == "dics" else "factor_update"
     kernels = [n for n in ops_of["custom-call"] if n.startswith(kernel)]
     assert kernels and {layers[n] for n in kernels} == {"update"}
-    assert "score" in {layers[n] for n in ops_of["sort"]}
+    if algorithm == "dics":
+        topn = [n for n in ops_of["custom-call"] if n.startswith("dics_topn")]
+        assert topn and {layers[n] for n in topn} == {"score"}
+        square = f",{I_CAP},{I_CAP}]"
+        assert not [n for n in ops_of.get("sort", [])
+                    if layers[n] == "score" and square in lines[n]]
+    else:
+        assert "score" in {layers[n] for n in ops_of["sort"]}
     assert "column_clear" in {layers[n] for n in ops_of["while"]}
