@@ -20,17 +20,22 @@ __all__ = ["stream_config", "session", "restored_session", "host_states"]
 
 def stream_config(doc: dict):
     from repro.core.algorithm import get_algorithm
+    from repro.core.forgetting import ForgettingConfig
     from repro.core.pipeline import StreamConfig
     from repro.core.routing import GridSpec
 
     u_cap, i_cap = cellspec.capacities(doc)
     hyper = get_algorithm(doc["algorithm"]).default_hyper()._replace(
         **doc["hyper"], u_cap=u_cap, i_cap=i_cap)
+    forget = cellspec.forgetting(doc)
+    extra = ({} if forget is None
+             else {"forgetting": ForgettingConfig(**forget)})
     return StreamConfig(algorithm=doc["algorithm"],
                         grid=GridSpec(doc["n_i"], doc["w"]),
                         micro_batch=doc["micro_batch"],
                         capacity_factor=doc["capacity_factor"], hyper=hyper,
-                        backend=doc["backend"], seed=doc["model_seed"])
+                        backend=doc["backend"], seed=doc["model_seed"],
+                        **extra)
 
 
 def _checked_config(doc: dict):

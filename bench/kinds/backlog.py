@@ -45,6 +45,7 @@ def run(h) -> None:
         jax.block_until_ready(session.states)
         h.calls.append((users, items))
         h.bits.append(res.recall.bits())
+        h.forgets.append(res.forgets)
         return res
 
     ingest(*feed.take(chunk))

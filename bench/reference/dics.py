@@ -18,6 +18,13 @@ the ``k_nn`` largest ``sim(p, q)`` over the user's rated ``q``; the hit
 is the event's item among the ``top_n`` best candidates (ties to the
 lower slot) with a score above 0.
 
+Forgetting, where the configuration states it (``cellspec.forgetting``),
+runs when ``srgrid.replay`` fires it: each evicted entry's bookkeeping
+resets (``srgrid.forget_tables``), a user's rated row is cleared, and an
+item's rated column, ``co`` row and column and count, at a cost that
+grows with the entries evicted. ``evicted`` counts the tenants evicted
+by a collision and by forgetting.
+
 ``dtype`` is the precision of the counts and of the similarity: the
 configuration states float32; the output check's control runs the same
 code in bfloat16. Nothing here imports the program.
@@ -44,6 +51,8 @@ class Worker:
         self.n_i, self.g = doc["n_i"], doc["n_i"] + doc["w"]
         n_c = cellspec.n_workers(doc)
         self.u_cap, self.i_cap = cellspec.capacities(doc)
+        self.forgetting = cellspec.forgetting(doc)
+        self.evicted = {"collision": 0, "forgetting": 0}
         self.k_nn, self.top_n = doc["hyper"]["k_nn"], doc["hyper"]["top_n"]
         u, i = self.u_cap, self.i_cap
         self.user_ids = np.full((n_c, u), -1, np.int32)
@@ -65,12 +74,10 @@ class Worker:
         old_u, old_i = self.user_ids[ws, su], self.item_ids[ws, si]
         new_u, new_i = old_u != us, old_i != its
         gone = new_u & (old_u >= 0)
+        gone_i = new_i & (old_i >= 0)
+        self.evicted["collision"] += int(gone.sum() + gone_i.sum())
         self.rated[ws[gone], su[gone], :] = False
-        for w, s in zip(ws[new_i & (old_i >= 0)], si[new_i & (old_i >= 0)]):
-            self.rated[w, :, s] = False
-            self.co[w, s, :] = 0
-            self.co[w, :, s] = 0
-            self.item_cnt[w, s] = 0
+        self._clear_items(ws[gone_i], si[gone_i])
 
         hist = self.rated[ws, su].astype(self.dtype)          # [m, I]
         self.co[ws, si, :] += hist
@@ -85,6 +92,18 @@ class Worker:
         self.user_ts[ws, su] = self.clock[ws]
         self.item_ts[ws, si] = self.clock[ws]
         self.rated[ws, su, si] = True
+
+    def _clear_items(self, ws, si):
+        self.rated[ws, :, si] = False
+        self.co[ws, si, :] = 0
+        self.co[ws, :, si] = 0
+        self.item_cnt[ws, si] = 0
+
+    def forget(self):
+        (uw, us), (iw, si) = srgrid.forget_tables(self, self.forgetting)
+        self.rated[uw, us, :] = False
+        self._clear_items(iw, si)
+        self.evicted["forgetting"] += uw.size + iw.size
 
     def hits(self, w, us, its):
         d = self.dtype

@@ -18,6 +18,12 @@ the ``top_n`` best-scored known, unrated items of the worker (ties to
 the lower slot), scored against the state at the start of the
 micro-batch with the user's vector (its initial vector when unknown).
 
+Forgetting, where the configuration states it (``cellspec.forgetting``),
+runs when ``srgrid.replay`` fires it: each evicted entry's bookkeeping
+resets (``srgrid.forget_tables``), its vector is zeroed and its rated
+row or column cleared, at a cost that grows with the entries evicted.
+``evicted`` counts the tenants evicted by a collision and by forgetting.
+
 ``dtype`` is the precision the factor arithmetic runs in: the
 configuration states float32; the output check's control runs the same
 code in bfloat16. Nothing here imports the program.
@@ -59,6 +65,8 @@ class Worker:
         self.n_i, self.g = doc["n_i"], doc["n_i"] + doc["w"]
         n_c = cellspec.n_workers(doc)
         self.u_cap, self.i_cap = cellspec.capacities(doc)
+        self.forgetting = cellspec.forgetting(doc)
+        self.evicted = {"collision": 0, "forgetting": 0}
         h = doc["hyper"]
         self.k, self.top_n = h["k"], h["top_n"]
         self.eta, self.lam = dtype(h["eta"]), dtype(h["lam"])
@@ -87,10 +95,11 @@ class Worker:
         old_u, old_i = self.user_ids[ws, su], self.item_ids[ws, si]
         new_u, new_i = old_u != us, old_i != its
         # Eviction of another tenant (a slot that held no id has no bits).
-        for w, s in zip(ws[new_i & (old_i >= 0)], si[new_i & (old_i >= 0)]):
-            self.rated[w, :, s] = False
+        gone_i = new_i & (old_i >= 0)
+        self.rated[ws[gone_i], :, si[gone_i]] = False
         gone = new_u & (old_u >= 0)
         self.rated[ws[gone], su[gone], :] = False
+        self.evicted["collision"] += int(gone.sum() + gone_i.sum())
 
         u = np.where(new_u[:, None], self.init_u[us], self.user_vecs[ws, su])
         i = np.where(new_i[:, None], self.init_i[its], self.item_vecs[ws, si])
@@ -106,6 +115,14 @@ class Worker:
         self.user_ts[ws, su] = self.clock[ws]
         self.item_ts[ws, si] = self.clock[ws]
         self.rated[ws, su, si] = True
+
+    def forget(self):
+        (uw, us), (iw, si) = srgrid.forget_tables(self, self.forgetting)
+        self.user_vecs[uw, us] = 0
+        self.item_vecs[iw, si] = 0
+        self.rated[uw, us, :] = False
+        self.rated[iw, :, si] = False
+        self.evicted["forgetting"] += uw.size + iw.size
 
     def hits(self, w, us, its):
         su, si = self._slots(us, its)

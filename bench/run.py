@@ -39,8 +39,9 @@ the configuration states and prints, on an earlier line, the numbers
 that control reads against the reference (the check's upper readings).
 
 Exit codes: 0 with a result line; 2 for a cell, file or program that is
-not there; 3 where JAX finds no TPU or fewer chips than the cell asks
-for; 4 where the stream producer made the trainer wait.
+not there, or a configuration that states what no reference replays
+(``cellspec.validate``); 3 where JAX finds no TPU or fewer chips than
+the cell asks for; 4 where the stream producer made the trainer wait.
 """
 
 from __future__ import annotations
@@ -118,6 +119,10 @@ class Cell:
         self.spec = cells[name]
         conf = {c["name"]: c for c in man["configs"]}[self.spec["config"]]
         self.doc = json.loads((root / conf["file"]).read_text())
+        try:
+            cellspec.validate(self.doc)
+        except ValueError as e:
+            fail(f"{conf['file']}: {e}", 2)
         self.traffic = json.loads(
             (HERE / "traffic" / f"{self.spec['traffic']}.json").read_text())
         self.end_to_end = [m for m in man["end_to_end"]
@@ -137,6 +142,7 @@ class Run:
         self.seed, self.seconds, self.trace = seed, seconds, trace
         self.calls: list = []      # (users, items) of every ingest call
         self.bits: list = []       # the program's prequential bits per call
+        self.forgets: list = []    # the program's forgetting triggers per call
         self.history = None        # (users, items) folded into a warm start
         self.first_window_call = 0
         self.e2e: dict = {}
@@ -302,11 +308,16 @@ def output_check(run: Run, states: dict, control: bool) -> tuple[bool, dict]:
     ref = ref_mod.Worker(doc)
     if warm is not None:
         ref.load(warm)
-    rep = srgrid.replay(run.calls, lay, ref, sample)
+    forget = cellspec.forgetting(doc)
+    every = forget["trigger_every"] if forget else None
+    rep = srgrid.replay(run.calls, lay, ref, sample, every)
     numbers = check.compare(states, ref.states(), ref_mod.FLOAT_LEAVES,
                             rows, rep, tol)
     log({"reference_s": time.perf_counter() - t0,
-         "reference_dropped": rep.dropped, **numbers})
+         "reference_dropped": rep.dropped,
+         "reference_forgets": rep.forgets,
+         "program_forgets": sum(run.forgets),
+         "reference_evicted": ref.evicted, **numbers})
     if control:
         import ml_dtypes
 
@@ -314,7 +325,7 @@ def output_check(run: Run, states: dict, control: bool) -> tuple[bool, dict]:
         low = ref_mod.Worker(doc, dtype=ml_dtypes.bfloat16)
         if warm is not None:
             low.load(warm)
-        low_rep = srgrid.replay(run.calls, lay, low, sample)
+        low_rep = srgrid.replay(run.calls, lay, low, sample, every)
         readings = check.compare(low.states(), ref.states(),
                                  ref_mod.FLOAT_LEAVES,
                                  replay_rows(low_rep, lay.row), rep, tol)

@@ -15,12 +15,17 @@ event goes and when it is trained:
   and what is still queued at the end of the call is dropped;
 * prequential evaluation: each event of a micro-batch is scored against
   its worker's state at the start of the micro-batch, then the worker
-  trains on its events in order.
+  trains on its events in order;
+* forgetting every ``c`` events of the stream (``forget_every``): after
+  each step that trained, the step's events are added to a count that
+  carries across ingest calls; where it reaches ``c``, every worker
+  forgets and ``c`` is taken off it, at most once a step.
 
 A ``worker`` object supplies the algorithm: ``train(ws, us, its)``
 applies one event on each of the distinct workers ``ws`` at once, and
 ``hits(w, us, its)`` scores a worker's events against its current state
-and returns their hit bits and margins (:func:`top_n_hits`). Nothing
+and returns their hit bits and margins (:func:`top_n_hits`), and
+``forget()`` runs the configuration's eviction on every worker. Nothing
 here imports the program.
 """
 
@@ -30,7 +35,8 @@ from typing import NamedTuple
 
 import numpy as np
 
-__all__ = ["Layout", "Replay", "replay", "route", "top_n_hits"]
+__all__ = ["Layout", "Replay", "replay", "route", "top_n_hits",
+           "forget_tables"]
 
 
 class Layout(NamedTuple):
@@ -56,6 +62,7 @@ class Replay(NamedTuple):
     hits: dict              # row index -> bool[row] (sampled rows only)
     margins: dict           # row index -> float[row], see top_n_hits
     dropped: int
+    forgets: int            # forgetting triggers fired
 
 
 def route(us, its, n_i: int, g: int):
@@ -94,6 +101,29 @@ def top_n_hits(scores: np.ndarray, target: np.ndarray, known: np.ndarray,
     return hit, np.where(decided, margin, np.inf)
 
 
+def forget_tables(worker, policy: dict):
+    """One forgetting trigger on the bookkeeping tables every reference
+    keeps (paper Sec. 5.2; ``policy`` as ``cellspec.forgetting`` gives
+    it). On each worker, LRU evicts the live entries with ``clock - ts >
+    lru_max_age``, LFU those with ``freq < lfu_min_freq``; an evicted
+    entry gets id -1, frequency and last-touch time 0. Returns the
+    evicted ``(workers, slots)`` of users and of items, for the worker
+    to clear what it learned of them."""
+    out = []
+    for ids, freq, ts in ((worker.user_ids, worker.user_freq, worker.user_ts),
+                          (worker.item_ids, worker.item_freq, worker.item_ts)):
+        if policy["policy"] == "lru":
+            stale = worker.clock[:, None] - ts > policy["lru_max_age"]
+        else:
+            stale = freq < policy["lfu_min_freq"]
+        w, s = np.nonzero((ids >= 0) & stale)
+        ids[w, s] = -1
+        freq[w, s] = 0
+        ts[w, s] = 0
+        out.append((w, s))
+    return out
+
+
 def _positions(keys: np.ndarray) -> np.ndarray:
     """Rank of each entry among the entries of its key, in order."""
     order = np.argsort(keys, kind="stable")
@@ -103,11 +133,14 @@ def _positions(keys: np.ndarray) -> np.ndarray:
     return pos
 
 
-def replay(calls, lay: Layout, worker, sample_rows=()) -> Replay:
+def replay(calls, lay: Layout, worker, sample_rows=(),
+           forget_every: int | None = None) -> Replay:
+    """Every ingest call of ``calls`` through the grid, in order;
+    ``forget_every`` is the forgetting cadence ``c`` (``None``: none)."""
     sample_rows = set(int(r) for r in sample_rows)
     mb, cap, carry, n_c = lay.micro_batch, lay.capacity, lay.carry, lay.n_c
     evaluated, hits, margins = [], {}, {}
-    dropped = 0
+    dropped = forgets = since = 0
     for users, items in calls:
         users = np.asarray(users, np.int64)
         items = np.asarray(items, np.int64)
@@ -153,8 +186,14 @@ def replay(calls, lay: Layout, worker, sample_rows=()) -> Replay:
                     break
                 idx = slots[ws, p]
                 worker.train(ws, bu[idx], bi[idx])
+            since += int(kept.sum())
+            if forget_every is not None and since >= forget_every:
+                worker.forget()
+                since -= forget_every
+                forgets += 1
             cu, ci = bu[over], bi[over]
         dropped += cu.size
     ev = (np.stack(evaluated) if evaluated
           else np.zeros((0, lay.row), bool))
-    return Replay(evaluated=ev, hits=hits, margins=margins, dropped=dropped)
+    return Replay(evaluated=ev, hits=hits, margins=margins, dropped=dropped,
+                  forgets=forgets)

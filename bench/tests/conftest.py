@@ -63,13 +63,16 @@ def load_run(root: Path):
     return mod
 
 
+#: The tiny test deployments: ``tiny-<name>`` is ``data/tiny-<name>.json``.
+TINY = ("disgd", "dics", "disgd-lru", "dics-lfu", "dics-lfu-collide")
+
+
 @pytest.fixture
 def tiny(tmp_path):
-    """``(run module, checkout root)`` with cells ``tiny-disgd.ingest``
-    and ``tiny-dics.ingest`` on the tiny test deployments."""
-    root = checkout(tmp_path, {"tiny-disgd": "tiny-disgd.json",
-                               "tiny-dics": "tiny-dics.json"},
+    """``(run module, checkout root)`` with a cell ``tiny-<name>.ingest``
+    on each tiny test deployment of :data:`TINY`."""
+    root = checkout(tmp_path, {f"tiny-{a}": f"tiny-{a}.json" for a in TINY},
                     [{"name": f"tiny-{a}.ingest", "config": f"tiny-{a}",
                       "traffic": "tiny-backlog", "chips": 1, "why": "test"}
-                     for a in ("disgd", "dics")])
+                     for a in TINY])
     return load_run(root), root

@@ -179,6 +179,7 @@ def test_window_rate_counts_a_stall(tiny, monkeypatch):
             time.sleep(0.005 + (self.stall if self.calls == 3 else 0.0))
             return SimpleNamespace(
                 events_processed=users.size, dropped=0, load_history=[1],
+                forgets=0,
                 recall=SimpleNamespace(bits=lambda: np.zeros(0)))
 
     def rate(stall):
@@ -197,12 +198,65 @@ def test_window_rate_counts_a_stall(tiny, monkeypatch):
     assert span >= 1.0
 
 
-def test_a_cell_and_a_metric_from_files_alone(tmp_path):
+@pytest.mark.parametrize("config", ["ml25m-disgd", "ml25m-dics"])
+def test_accepted_configurations_build_the_same_program(config):
+    """The accepted configurations state no forgetting and collision-free
+    tables, and build the ``StreamConfig`` they built before either
+    could be stated."""
+    import cellspec
+    import deploy
+    from repro.core.algorithm import get_algorithm
+    from repro.core.pipeline import StreamConfig
+    from repro.core.routing import GridSpec
+
+    doc = json.loads((BENCH / "configs" / f"{config}.json").read_text())
+    assert cellspec.forgetting(doc) is None
+    u_cap, i_cap = cellspec.capacities(doc)
+    hyper = get_algorithm(doc["algorithm"]).default_hyper()._replace(
+        **doc["hyper"], u_cap=u_cap, i_cap=i_cap)
+    want = StreamConfig(algorithm=doc["algorithm"],
+                        grid=GridSpec(doc["n_i"], doc["w"]),
+                        micro_batch=doc["micro_batch"],
+                        capacity_factor=doc["capacity_factor"], hyper=hyper,
+                        backend=doc["backend"], seed=doc["model_seed"])
+    assert deploy.stream_config(doc) == want
+
+
+@pytest.mark.parametrize("setting", [
+    {"forgetting": {"policy": "gradual", "trigger_every": 512}},
+    {"drift": {"mode": "adaptive"}},
+    {"storage": {"co": "uint16"}},
+    {"warm_periods": 2, "forgetting": {"policy": "lfu", "trigger_every": 512,
+                                       "lfu_min_freq": 2}},
+    {"forgetting": {"policy": "lru", "trigger_every": 8, "lru_max_age": 9}},
+    {"forgetting": {"policy": "lru", "trigger_every": 512}},
+    {"capacity": {"u_cap": 100, "i_cap": 128}},
+    {"capacity": {"u_cap": 128, "i_cap": 512}},
+    {"capacity": "unbounded"},
+], ids=["gradual", "drift", "storage", "forgetting-warm", "cadence-short",
+        "threshold-missing", "capacity-unaligned", "capacity-too-large",
+        "capacity-rule"])
+def test_a_setting_no_reference_replays_is_refused(tmp_path, setting):
+    """A configuration that states what no reference replays exits with
+    code 2 before a run starts."""
+    root = checkout(tmp_path, {"tiny-new": "tiny-disgd.json"},
+                    [{"name": "tiny-new.ingest", "config": "tiny-new",
+                      "traffic": "tiny-backlog", "chips": 1, "why": "test"}])
+    path = root / "bench" / "configs" / "tiny-new.json"
+    path.write_text(json.dumps({**json.loads(path.read_text()), **setting}))
+    run = load_run(root)
+    with pytest.raises(SystemExit) as e:
+        run.Cell(root, "tiny-new.ingest")
+    assert e.value.code == 2
+
+
+@pytest.mark.parametrize("config", ["tiny-disgd.json", "tiny-disgd-lru.json"])
+def test_a_cell_and_a_metric_from_files_alone(tmp_path, config):
     metric = ('"""Events folded in per live step (test metric)."""\n\n'
               "def read(ctx):\n"
               "    return ctx.processed / ctx.live_steps\n")
     root = checkout(
-        tmp_path, {"tiny-new": "tiny-disgd.json"},
+        tmp_path, {"tiny-new": config},
         [{"name": "tiny-new.ingest", "config": "tiny-new",
           "traffic": "tiny-backlog", "chips": 1, "why": "test"}],
         extra={"bench/metrics/events_per_step.py": metric})
