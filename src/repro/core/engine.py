@@ -201,13 +201,15 @@ def _make_batch_step(cfg, worker_fn):
         # Precision@N denominator, measured on the bucket-start states
         # (before this batch trains) — the same expression the host loop
         # folds, so the two backends stay bit-identical.
-        list_len = 0
+        list_len = collisions = 0
         if tel_on:
             with trace_lib.layer("telemetry"):
                 list_len = telemetry_lib.effective_list_len(
                     states, ev_u.astype(jnp.int32),
                     top_n=cfg.resolved_hyper().top_n, g=g,
                     storage=cfg.storage)
+                collisions = telemetry_lib.collision_evictions(
+                    states, ev_u, ev_i, g=g, n_i=n_i)
         states, hits, evaluated = worker_fn(
             states, ev_u.astype(jnp.int32), ev_i.astype(jnp.int32)
         )
@@ -235,9 +237,8 @@ def _make_batch_step(cfg, worker_fn):
 
             kept_n = jnp.sum(kept.astype(jnp.int32))
             processed = processed + kept_n
-            since = since + kept_n
         fired = jnp.zeros((), jnp.int32)
-        evicted = jnp.zeros((), jnp.int32)
+        triggers = evicted = jnp.zeros((), jnp.int32)
         if adaptive:
             with trace_lib.layer("forget"):
                 det = detector_lib.detector_update(
@@ -250,10 +251,11 @@ def _make_batch_step(cfg, worker_fn):
                     # rows; only the net occupancy drop counts as
                     # evictions.
                     evicted = jnp.maximum(occ_before - _occ_total(states), 0)
-                forgets = forgets + det.fired.astype(jnp.int32)
-                fired = det.fired.astype(jnp.int32)
+                fired = triggers = det.fired.astype(jnp.int32)
+                forgets = forgets + fired
         elif forget is not None:
             with trace_lib.layer("forget"):
+                since = since + kept_n
                 trigger = since >= cfg.forgetting.trigger_every
                 if tel_on:
                     def _forget_counted(s):
@@ -276,7 +278,8 @@ def _make_batch_step(cfg, worker_fn):
                 # mb <= te.
                 since = jnp.where(trigger,
                                   since - cfg.forgetting.trigger_every, since)
-                forgets = forgets + trigger.astype(jnp.int32)
+                triggers = trigger.astype(jnp.int32)
+                forgets = forgets + triggers
 
         if tel_on:
             with trace_lib.layer("telemetry"):
@@ -285,7 +288,8 @@ def _make_batch_step(cfg, worker_fn):
                     tel, kept=kept_n, overflow=n_overflow,
                     carry_cap=carry_cap, evicted=evicted, hits=hits,
                     evaluated=evaluated, load=load, occupancy=u_o + i_o,
-                    list_len=list_len)
+                    list_len=list_len, collisions=collisions,
+                    triggers=triggers)
 
         carry = (states, cu_new, ci_new, since, processed, dropped, forgets,
                  det, boost, tel)
@@ -321,9 +325,12 @@ def _scan_program(batch_step):
     return jax.jit(engine_scan)
 
 
-def init_scan_carry(cfg, states=None, carry=(None, None), detector=None):
+def init_scan_carry(cfg, states=None, carry=(None, None), detector=None,
+                    since: int = 0):
     """Initial scan carry; ``states``/``carry``/``detector`` resume from a
-    checkpoint (``detector`` is a ``DetectorState``-shaped tuple)."""
+    checkpoint (``detector`` is a ``DetectorState``-shaped tuple), and
+    ``since`` continues the fixed forgetting cadence: the events folded
+    since its last trigger, in this call or an earlier one."""
     from repro.core import pipeline
 
     if states is None:
@@ -349,7 +356,8 @@ def init_scan_carry(cfg, states=None, carry=(None, None), detector=None):
     zero = jnp.zeros((), jnp.int32)
     # The telemetry slot rides along even with cfg.telemetry=False (zeros,
     # never updated) so the carry structure is config-independent.
-    return (states, cu, ci, zero, zero, jnp.asarray(lost, jnp.int32), zero,
+    return (states, cu, ci, jnp.asarray(since, jnp.int32), zero,
+            jnp.asarray(lost, jnp.int32), zero,
             det, controller_lib.controller_init(),
             telemetry_lib.telemetry_init(cfg.grid.n_c))
 
@@ -445,7 +453,7 @@ def run_stream_device(users: np.ndarray, items: np.ndarray, cfg,
                       publish_every: int = 0, on_publish=None,
                       publish_sync: bool = True,
                       initial_states=None, initial_carry=(None, None),
-                      initial_detector=None):
+                      initial_detector=None, initial_since: int = 0):
     """Run the whole prequential stream as a jitted scan on device.
 
     With ``publish_every == 0`` (default) the stream is one scan call.
@@ -467,6 +475,8 @@ def run_stream_device(users: np.ndarray, items: np.ndarray, cfg,
     ``initial_states``/``initial_carry`` resume from a checkpoint or a
     regridded state; shapes must match ``cfg`` (the compiled scan is
     shape-polymorphic in values only), so regrid to ``cfg.grid`` first.
+    ``initial_since`` is the forgetting cadence's count carried from the
+    previous call (``StreamResult.events_since_trigger``).
     """
     from repro.core.pipeline import StreamResult
 
@@ -496,7 +506,8 @@ def run_stream_device(users: np.ndarray, items: np.ndarray, cfg,
 
         carry0 = init_scan_carry(cfg, states=initial_states,
                                  carry=initial_carry,
-                                 detector=initial_detector)
+                                 detector=initial_detector,
+                                 since=initial_since)
         xs = (jnp.asarray(fu, jnp.int32), jnp.asarray(fi, jnp.int32))
 
         # AOT-compile so the wall clock measures steady-state streaming,
@@ -551,7 +562,7 @@ def run_stream_device(users: np.ndarray, items: np.ndarray, cfg,
                 with trace_lib.span("boundary"):
                     on_publish(ev)
                 publish_time += time.perf_counter() - tp
-        states, cu, ci, _, processed, dropped, forgets, det, _, tel = carry
+        states, cu, ci, since, processed, dropped, forgets, det, _, tel = carry
         jax.block_until_ready(states)
     wall = time.perf_counter() - t0 - publish_time
 
@@ -595,6 +606,7 @@ def run_stream_device(users: np.ndarray, items: np.ndarray, cfg,
         load_history=load_history,
         final_states=states,
         forgets=int(forgets),
+        events_since_trigger=int(since),
         drift_flags=drift_flags,
         final_detector=final_detector,
         telemetry=telemetry,

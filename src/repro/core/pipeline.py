@@ -109,6 +109,10 @@ class StreamResult:
     final_states: Any = None
     # Forgetting passes fired (fixed cadence or adaptive controller).
     forgets: int = 0
+    # Events folded since the fixed cadence's last trigger: the count the
+    # next call continues from (``run_stream(initial_since=...)``), so
+    # the cadence follows the stream, not the calls. 0 without a cadence.
+    events_since_trigger: int = 0
     # Per-micro-batch detector flags (i32[steps]) when the adaptive drift
     # policy is active, else None.
     drift_flags: Any = None
@@ -202,7 +206,8 @@ def run_stream(users: np.ndarray, items: np.ndarray, cfg: StreamConfig,
                on_publish=None, publish_sync: bool = True,
                initial_states=None,
                initial_carry=(None, None),
-               initial_detector=None) -> StreamResult:
+               initial_detector=None,
+               initial_since: int = 0) -> StreamResult:
     """Run the full prequential stream; returns curves + paper metrics.
 
     Thin dispatcher: ``cfg.backend`` selects the host reference loop below
@@ -223,6 +228,8 @@ def run_stream(users: np.ndarray, items: np.ndarray, cfg: StreamConfig,
     ``restore_stream_checkpoint`` (which regrids portable checkpoints to
     the configured grid) or call ``regrid.regrid`` first.
     ``events_processed``/recall in the result cover the resumed segment.
+    ``initial_since`` continues the fixed forgetting cadence from the
+    previous call's ``StreamResult.events_since_trigger``.
     """
     # Backend selection negotiates against the algorithm's capability
     # flags (e.g. pallas without a fast path degrades to scan, with one
@@ -235,7 +242,7 @@ def run_stream(users: np.ndarray, items: np.ndarray, cfg: StreamConfig,
             publish_every=publish_every, on_publish=on_publish,
             publish_sync=publish_sync,
             initial_states=initial_states, initial_carry=initial_carry,
-            initial_detector=initial_detector)
+            initial_detector=initial_detector, initial_since=initial_since)
 
     assert users.shape == items.shape
     n = users.shape[0]
@@ -282,7 +289,7 @@ def run_stream(users: np.ndarray, items: np.ndarray, cfg: StreamConfig,
     processed = 0
     carry_u, carry_i = (np.asarray(c, np.int64) if c is not None
                         else np.empty(0, np.int64) for c in initial_carry)
-    events_since_trigger = 0
+    events_since_trigger = initial_since
     forgets = 0
     published_steps = 0
 
@@ -305,6 +312,8 @@ def run_stream(users: np.ndarray, items: np.ndarray, cfg: StreamConfig,
         list_fn = jax.jit(partial(telemetry_lib.effective_list_len,
                                   top_n=cfg.resolved_hyper().top_n,
                                   g=grid.g, storage=cfg.storage))
+        collision_fn = jax.jit(partial(telemetry_lib.collision_evictions,
+                                       g=grid.g, n_i=grid.n_i))
         occ_total = jax.jit(
             lambda s: sum(jnp.sum(o) for o in
                           jax.vmap(lambda w: state_lib.occupancy(w.tables))(s)
@@ -337,8 +346,10 @@ def run_stream(users: np.ndarray, items: np.ndarray, cfg: StreamConfig,
         jax.block_until_ready(tel_step(
             tel, kept=zero, overflow=zero, evicted=zero, hits=dummy_b,
             evaluated=dummy_b, load=jnp.zeros((grid.n_c,), jnp.int32),
-            occupancy=jnp.zeros((grid.n_c,), jnp.int32), list_len=zero))
+            occupancy=jnp.zeros((grid.n_c,), jnp.int32), list_len=zero,
+            collisions=zero, triggers=zero))
         jax.block_until_ready(list_fn(states, dummy))
+        jax.block_until_ready(collision_fn(states, dummy, dummy))
         jax.block_until_ready(occ_total(states))
 
     t0 = time.perf_counter()
@@ -379,18 +390,19 @@ def run_stream(users: np.ndarray, items: np.ndarray, cfg: StreamConfig,
         ev_u_j = jnp.asarray(ev_u, jnp.int32)
         # Precision@N denominator from the pre-step states (the engine
         # computes it at the same point inside its scan body).
-        lens = list_fn(states, ev_u_j) if list_fn is not None else None
-        states, hits, evaluated = step(
-            states, ev_u_j, jnp.asarray(ev_i, jnp.int32)
-        )
+        ev_i_j = jnp.asarray(ev_i, jnp.int32)
+        lens = collisions = None
+        if tel is not None:
+            lens = list_fn(states, ev_u_j)
+            collisions = collision_fn(states, ev_u_j, ev_i_j)
+        states, hits, evaluated = step(states, ev_u_j, ev_i_j)
 
         # Stream-order scatter needs bucket indices relative to this batch.
         acc.add_batch(buckets, np.asarray(hits), np.asarray(evaluated), bu.shape[0])
         processed += int(kept.sum())
         loads.append(load)
 
-        events_since_trigger += int(kept.sum())
-        evicted = 0
+        evicted = triggers = 0
         if adaptive:
             det = det_update(det, hits, evaluated)
             occ_before = occ_total(states) if tel is not None else None
@@ -399,17 +411,21 @@ def run_stream(users: np.ndarray, items: np.ndarray, cfg: StreamConfig,
                 evicted = max(int(occ_before) - int(occ_total(states)), 0)
             fired = bool(det.fired)
             drift_flags.append(fired)
-            forgets += int(fired)
-        elif (forget is not None
-                and events_since_trigger >= cfg.forgetting.trigger_every):
-            occ_before = occ_total(states) if tel is not None else None
-            states = forget(states)
-            if tel is not None:
-                evicted = int(occ_before) - int(occ_total(states))
-            # Carry the remainder (see engine._make_batch_step): resetting
-            # to zero would alias the cadence onto micro-batch boundaries.
-            events_since_trigger -= cfg.forgetting.trigger_every
-            forgets += 1
+            triggers = int(fired)
+            forgets += triggers
+        elif forget is not None:
+            events_since_trigger += int(kept.sum())
+            if events_since_trigger >= cfg.forgetting.trigger_every:
+                occ_before = occ_total(states) if tel is not None else None
+                states = forget(states)
+                if tel is not None:
+                    evicted = int(occ_before) - int(occ_total(states))
+                # Carry the remainder (see engine._make_batch_step):
+                # resetting to zero would alias the cadence onto
+                # micro-batch boundaries.
+                events_since_trigger -= cfg.forgetting.trigger_every
+                triggers = 1
+                forgets += 1
         if tel is not None:
             u_o, i_o = occ_fn(states)
             tel = tel_step(tel, kept=jnp.asarray(int(kept.sum()), jnp.int32),
@@ -417,7 +433,9 @@ def run_stream(users: np.ndarray, items: np.ndarray, cfg: StreamConfig,
                            evicted=jnp.asarray(evicted, jnp.int32),
                            hits=hits, evaluated=evaluated,
                            load=jnp.asarray(load, jnp.int32),
-                           occupancy=u_o + i_o, list_len=lens)
+                           occupancy=u_o + i_o, list_len=lens,
+                           collisions=collisions,
+                           triggers=jnp.asarray(triggers, jnp.int32))
 
         if publish_every and on_publish is not None and (b + 1) % publish_every == 0:
             # Sync in-flight device work (async forgetting dispatch) before
@@ -470,6 +488,7 @@ def run_stream(users: np.ndarray, items: np.ndarray, cfg: StreamConfig,
         load_history=loads,
         final_states=states,
         forgets=forgets,
+        events_since_trigger=events_since_trigger,
         drift_flags=(np.asarray(drift_flags, np.int32) if adaptive else None),
         final_detector=(jax.tree.map(np.asarray, det) if adaptive else None),
         telemetry=(jax.tree.map(np.asarray, tel) if tel is not None else None),
@@ -488,7 +507,8 @@ LOGICAL_FORMAT = "sr-logical-v1"
 
 def save_stream_checkpoint(directory: str, events_processed: int, states,
                            carry=(None, None), grid=None, algorithm=None,
-                           detector=None, storage: StoragePolicy = None):
+                           detector=None, storage: StoragePolicy = None,
+                           since: int = 0):
     """Persist worker states (+ the re-queue carry) mid-stream.
 
     With ``grid`` (the ``GridSpec`` the states are shaped for), the
@@ -512,6 +532,8 @@ def save_stream_checkpoint(directory: str, events_processed: int, states,
     ``StreamResult.final_detector`` or ``PublishEvent.detector``) rides
     along in either format — the detector's scalars are grid-agnostic —
     so a closed-loop run resumes without re-warming drift detection.
+    ``since`` (``StreamResult.events_since_trigger``) does the same for
+    the fixed forgetting cadence: it belongs to the stream, not the grid.
     """
     from repro.checkpoint import save_checkpoint
 
@@ -524,6 +546,7 @@ def save_stream_checkpoint(directory: str, events_processed: int, states,
         "carry_i": np.asarray(carry_i if carry_i is not None else
                               np.empty(0, np.int64)),
         "storage": storage.describe(),
+        "since": np.asarray(since, np.int64),
     }
     if detector is not None:
         tree["detector"] = jax.tree.map(np.asarray, detector)
@@ -571,7 +594,8 @@ class RestoredCheckpoint:
     the ``(carry_u, carry_i)`` overflow re-queue; ``detector`` is the
     saved drift ``DetectorState`` (a tuple of host arrays for
     ``run_stream(initial_detector=...)``) or ``None`` for checkpoints
-    written without one.
+    written without one. ``since`` is the fixed forgetting cadence's
+    count (0 for checkpoints written without one).
 
     The legacy ``(events_processed, states, carry, detector)`` 4-tuple
     iteration shipped for one release of back-compat (PR 5) and is now
@@ -584,6 +608,7 @@ class RestoredCheckpoint:
     states: Any
     carry: tuple
     detector: Any = None
+    since: int = 0
 
 
 def restore_stream_checkpoint(directory: str, cfg: StreamConfig,
@@ -606,6 +631,7 @@ def restore_stream_checkpoint(directory: str, cfg: StreamConfig,
     events_processed, tree = restore_checkpoint(directory, step)
     carry = (tree["carry_u"], tree["carry_i"])
     detector = tree.get("detector")
+    since = int(tree.get("since", 0))
     hyper = cfg.resolved_hyper()
     algo = algorithm_lib.get_algorithm(cfg.algorithm)
 
@@ -646,7 +672,8 @@ def restore_stream_checkpoint(directory: str, cfg: StreamConfig,
         states = algo.build_states(
             logical, src=src, dst=cfg.grid,
             u_cap=hyper.u_cap, i_cap=hyper.i_cap, storage=cfg.storage)
-        return RestoredCheckpoint(events_processed, states, carry, detector)
+        return RestoredCheckpoint(events_processed, states, carry, detector,
+                                  since)
 
     # Legacy fixed-shape payload: validate against the algorithm's
     # checkpoint schema (single-worker template stacked over the grid,
@@ -674,4 +701,4 @@ def restore_stream_checkpoint(directory: str, cfg: StreamConfig,
         treedef,
         [jnp.asarray(s, t.dtype) for s, t in zip(flat_s, flat_t)],
     )
-    return RestoredCheckpoint(events_processed, states, carry, detector)
+    return RestoredCheckpoint(events_processed, states, carry, detector, since)

@@ -24,7 +24,7 @@ from typing import NamedTuple
 
 import jax.numpy as jnp
 
-__all__ = ["Plan", "plan_bucket", "first_index"]
+__all__ = ["Plan", "plan_bucket", "first_index", "held_ids"]
 
 # Per-event flag bits handed to the kernels.
 VALID, NEW_U, NEW_I, NEG_OK = 1, 2, 4, 8
@@ -54,6 +54,18 @@ def _live(table, slots, same, before_valid, values):
     """Table value at ``slots`` as seen by each event, before its own write."""
     last = _latest(same & before_valid)
     return jnp.where(last >= 0, values[jnp.maximum(last, 0)], table[slots])
+
+
+def held_ids(table, ids, slots, valid):
+    """The id each event of a bucket finds in its slot, before its own
+    write: the latest earlier valid event's of that slot, else the
+    bucket-start ``table``'s. An event is new in its slot where this
+    differs from its id, and evicts a live tenant where it is also
+    ``>= 0``."""
+    idx = jnp.arange(ids.shape[0], dtype=jnp.int32)
+    before_valid = (idx[None, :] < idx[:, None]) & valid[None, :]
+    return _live(table, slots, slots[:, None] == slots[None, :],
+                 before_valid, ids)
 
 
 def _counter_after(c0, slots, same, upto, resets, adds):

@@ -15,6 +15,10 @@ The vector counts, cumulatively within one ``run_stream`` call:
   * ``requeued``   — overflow events re-queued for a later micro-batch;
   * ``evictions``  — table entries freed by forgetting / drift control
     (occupancy delta across the forgetting op);
+  * ``collisions`` — live tenants replaced by an id that shares their
+    slot (collision eviction; occupancy does not change);
+  * ``triggers``   — forgetting passes fired (fixed cadence or the
+    adaptive controller);
   * ``hits`` / ``evals`` — prequential recall numerator / denominator;
   * ``bucket_hwm`` — per-bucket dispatch-load high-water mark
     (``i32[n_c]``; the skew/pressure signal the ROADMAP's autoscaler
@@ -46,7 +50,7 @@ import numpy as np
 
 __all__ = ["TelemetryState", "telemetry_init", "telemetry_update",
            "telemetry_batch_update", "telemetry_ints", "TelemetryFolder",
-           "effective_list_len", "HOST_CARRY_CAP"]
+           "effective_list_len", "collision_evictions", "HOST_CARRY_CAP"]
 
 # The host reference loop re-queues overflow into an unbounded Python
 # list; folding with this capacity makes "never drops, always requeues"
@@ -70,17 +74,20 @@ class TelemetryState(NamedTuple):
                              # precision@N denominator (min(top_n,
                              # unrated candidates) per kept event,
                              # measured at bucket start)
+    collisions: jnp.ndarray  # live tenants evicted by a colliding id
+    triggers: jnp.ndarray    # forgetting passes fired
 
 
 def telemetry_init(n_c: int) -> TelemetryState:
     z = jnp.zeros((), jnp.int32)
     return TelemetryState(z, z, z, z, z, z, jnp.zeros((n_c,), jnp.int32),
-                          jnp.zeros((n_c,), jnp.int32), z)
+                          jnp.zeros((n_c,), jnp.int32), z, z, z)
 
 
 def telemetry_update(tel: TelemetryState, *, kept, overflow, carry_cap,
                      evicted, hits, evals, load,
-                     occupancy=None, list_len=0) -> TelemetryState:
+                     occupancy=None, list_len=0, collisions=0,
+                     triggers=0) -> TelemetryState:
     """Fold one micro-batch of scalar counts into the running vector.
 
     Pure integer arithmetic so host and scan backends produce
@@ -89,7 +96,9 @@ def telemetry_update(tel: TelemetryState, *, kept, overflow, carry_cap,
     optional — ``None`` leaves the occupancy high-water mark unchanged.
     ``list_len`` is the batch's summed effective recommendation-list
     length (:func:`effective_list_len`) — the precision@N denominator;
-    callers without a precision head leave it at 0.
+    callers without a precision head leave it at 0. ``collisions``
+    (:func:`collision_evictions`) and ``triggers`` (forgetting passes
+    fired) default to 0 likewise.
     """
     overflow = jnp.asarray(overflow, jnp.int32)
     carry_cap = jnp.asarray(carry_cap, jnp.int32)
@@ -107,13 +116,15 @@ def telemetry_update(tel: TelemetryState, *, kept, overflow, carry_cap,
                                jnp.asarray(load, jnp.int32)),
         occ_hwm=occ_hwm,
         list_len=tel.list_len + jnp.asarray(list_len, jnp.int32),
+        collisions=tel.collisions + jnp.asarray(collisions, jnp.int32),
+        triggers=tel.triggers + jnp.asarray(triggers, jnp.int32),
     )
 
 
 def telemetry_batch_update(tel: TelemetryState, *, kept, overflow,
                            carry_cap, evicted, hits, evaluated,
-                           load, occupancy=None,
-                           list_len=0) -> TelemetryState:
+                           load, occupancy=None, list_len=0,
+                           collisions=0, triggers=0) -> TelemetryState:
     """:func:`telemetry_update` with the recall reduction inlined.
 
     ``hits`` / ``evaluated`` are the worker step's ``bool[n_c, cap]``
@@ -125,7 +136,8 @@ def telemetry_batch_update(tel: TelemetryState, *, kept, overflow,
         evicted=evicted,
         hits=jnp.sum((hits & evaluated).astype(jnp.int32)),
         evals=jnp.sum(evaluated.astype(jnp.int32)), load=load,
-        occupancy=occupancy, list_len=list_len)
+        occupancy=occupancy, list_len=list_len, collisions=collisions,
+        triggers=triggers)
 
 
 def effective_list_len(states, ev_u, *, top_n: int, g: int, storage):
@@ -166,6 +178,38 @@ def effective_list_len(states, ev_u, *, top_n: int, g: int, storage):
     return jnp.sum(jax.vmap(per_worker)(states, ev_u)).astype(jnp.int32)
 
 
+def collision_evictions(states, ev_u, ev_i, *, g: int, n_i: int):
+    """Live tenants one dispatched micro-batch evicts by collision.
+
+    An event evicts where its slot, as the event finds it, holds another
+    live id (``kernels.bookkeeping.held_ids``, the workers' own "new in
+    its slot" rule). Users and items both count; padding (``-1``)
+    evicts nothing. ``states`` are the
+    bucket-start ``[n_c, ...]`` states and ``ev_u``/``ev_i`` the
+    dispatch's ``i32[n_c, cap]`` layout, as for
+    :func:`effective_list_len`.
+    """
+    from repro.core import state as state_lib
+    from repro.kernels.bookkeeping import held_ids
+
+    def replaced(table, ids, slots, valid):
+        held = held_ids(table, ids, slots, valid)
+        return jnp.sum((valid & (held >= 0) & (held != ids))
+                       .astype(jnp.int32))
+
+    def per_worker(st, eu, ei):
+        t = st.tables
+        valid = eu >= 0
+        u_slot = state_lib.slot_of(eu, g, t.user_ids.shape[-1])
+        i_slot = state_lib.slot_of(ei, n_i, t.item_ids.shape[-1])
+        return (replaced(t.user_ids, eu, u_slot, valid)
+                + replaced(t.item_ids, ei, i_slot, valid))
+
+    return jnp.sum(jax.vmap(per_worker)(
+        states, jnp.asarray(ev_u, jnp.int32),
+        jnp.asarray(ev_i, jnp.int32))).astype(jnp.int32)
+
+
 def telemetry_ints(tel: TelemetryState) -> dict:
     """Host-int view of a telemetry vector (blocks on device reads)."""
     return {
@@ -178,6 +222,8 @@ def telemetry_ints(tel: TelemetryState) -> dict:
         "bucket_hwm": [int(v) for v in np.asarray(tel.bucket_hwm)],
         "occ_hwm": [int(v) for v in np.asarray(tel.occ_hwm)],
         "list_len": int(tel.list_len),
+        "collisions": int(tel.collisions),
+        "triggers": int(tel.triggers),
     }
 
 
@@ -193,7 +239,7 @@ class TelemetryFolder:
     """
 
     _SCALARS = ("events", "dropped", "requeued", "evictions", "hits",
-                "evals", "list_len")
+                "evals", "list_len", "collisions", "triggers")
 
     def __init__(self, registry):
         self.registry = registry
@@ -220,6 +266,12 @@ class TelemetryFolder:
             "list_len": registry.counter(
                 "stream_list_len_total", "Summed effective top-N list "
                 "length (precision@N denominator)"),
+            "collisions": registry.counter(
+                "stream_collision_evictions_total", "Live table entries "
+                "evicted by an id that shares their slot"),
+            "triggers": registry.counter(
+                "stream_forget_triggers_total", "Forgetting passes fired "
+                "(fixed cadence or drift controller)"),
         }
         self._hwm = registry.gauge(
             "stream_bucket_hwm", "Per-bucket dispatch-load high-water "

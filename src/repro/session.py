@@ -96,6 +96,9 @@ class StreamSession:
         self._states = pipeline_lib.init_states(cfg)
         self._carry: tuple = (None, None)
         self._detector: Any = None
+        # The fixed forgetting cadence's count: it follows the stream
+        # across ingest calls, checkpoints and regrids.
+        self._since = 0
         self.events_processed = 0
         self.forgets = 0
         hyper = cfg.resolved_hyper()
@@ -171,8 +174,9 @@ class StreamSession:
                 on_publish=hook,
                 publish_sync=not policy.is_async,
                 initial_states=self._states, initial_carry=self._carry,
-                initial_detector=self._detector)
+                initial_detector=self._detector, initial_since=self._since)
         self._states = res.final_states
+        self._since = res.events_since_trigger
         # run_stream drains the re-queue before returning (flushed or
         # counted in res.dropped), so the carry is consumed.
         self._carry = (None, None)
@@ -227,12 +231,13 @@ class StreamSession:
     # -- checkpoint / restore ---------------------------------------------
 
     def checkpoint(self, directory: str) -> str:
-        """Write a grid-portable checkpoint (detector state included)."""
+        """Write a grid-portable checkpoint (detector state and the
+        forgetting cadence included)."""
         return save_stream_checkpoint(
             directory, self.events_processed, self._states,
             carry=self._carry, grid=self.cfg.grid,
             algorithm=self.cfg.algorithm, detector=self._detector,
-            storage=self.cfg.storage)
+            storage=self.cfg.storage, since=self._since)
 
     @classmethod
     def restore(cls, directory: str, cfg: StreamConfig,
@@ -256,6 +261,7 @@ class StreamSession:
         session._states = ck.states
         session._carry = ck.carry
         session._detector = ck.detector
+        session._since = ck.since
         session.events_processed = int(ck.events_processed)
         session._publish()
         return session

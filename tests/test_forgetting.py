@@ -2,6 +2,7 @@
 
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from tests.prop import given, settings, st
 
@@ -286,3 +287,256 @@ def test_gradual_dics_decay_survives_coarsening():
     iids = np.asarray(out.tables.item_ids).reshape(-1)
     assert sorted(iids[iids >= 0].tolist()) == list(range(8))
     assert np.all(np.asarray(out.item_cnt).reshape(-1)[iids >= 0] == 2.0)
+
+
+# ---------------------------------------------------------------------------
+# The fixed cadence follows the stream across ingest calls (scan backend)
+# ---------------------------------------------------------------------------
+
+CADENCE = 768          # not a multiple of the 512-event calls
+CALL = 512
+
+
+def _cadence_cfg(backend="scan"):
+    """LRU on tables below the ids' count (collisions too); the bucket
+    holds a whole micro-batch, so nothing is re-queued and calls of any
+    size cut the stream into the same micro-batches."""
+    from repro.core.disgd import DisgdHyper
+    from repro.core.pipeline import StreamConfig
+    from repro.core.routing import GridSpec
+
+    return StreamConfig(
+        algorithm="disgd", grid=GridSpec(2), micro_batch=64,
+        capacity_factor=4.0, hyper=DisgdHyper(u_cap=64, i_cap=32),
+        forgetting=ForgettingConfig(policy="lru", trigger_every=CADENCE,
+                                    lru_max_age=200),
+        backend=backend)
+
+
+def _cadence_stream(n=7 * CALL):
+    from repro.data.stream import MOVIELENS_25M, scaled, synth_stream
+
+    users, items, _ = synth_stream(scaled(MOVIELENS_25M, 0.005), seed=3)
+    return users[:n], items[:n]
+
+
+def _ingest_calls(session, users, items):
+    """Per-call triggers, the clean prequential bits of all calls and
+    the cadence's count after the last."""
+    forgets, bits = [], []
+    for lo in range(0, users.size, CALL):
+        res = session.ingest(users[lo:lo + CALL], items[lo:lo + CALL])
+        forgets.append(res.forgets)
+        b = res.recall.bits()
+        bits.append(b[~np.isnan(b)])
+    return forgets, np.concatenate(bits), res.events_since_trigger
+
+
+def _assert_states_equal(a, b):
+    import jax
+
+    for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b)):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+
+def test_cadence_spans_ingest_calls():
+    """Calls of 512 events with a cadence of 768 fire floor(N / 768)
+    triggers in all, at the same steps as one call of N events, and end
+    in bit-identical states."""
+    from repro.session import StreamSession
+
+    users, items = _cadence_stream()
+    split = StreamSession(_cadence_cfg())
+    forgets, bits, since = _ingest_calls(split, users, items)
+    whole = StreamSession(_cadence_cfg())
+    res = whole.ingest(users, items)
+
+    assert res.dropped == 0 and res.events_processed == users.size
+    assert sum(forgets) == res.forgets == users.size // CADENCE
+    # 512, 1024, ..., 3584 events: a trigger at 768, 1536, 2304, 3072.
+    assert forgets == [0, 1, 1, 0, 1, 1, 0]
+    assert since == res.events_since_trigger == users.size % CADENCE
+    whole_bits = res.recall.bits()
+    np.testing.assert_array_equal(bits, whole_bits[~np.isnan(whole_bits)])
+    _assert_states_equal(split.states, whole.states)
+    assert int(split.metrics.counter("stream_forget_triggers_total",
+                                     "").value) == sum(forgets)
+
+
+def test_cadence_survives_checkpoint_and_restore(tmp_path):
+    """A checkpoint taken between two triggers carries the cadence: the
+    restored session fires where the uninterrupted one does. One
+    written without the count restores 0."""
+    from repro.core.pipeline import (restore_stream_checkpoint,
+                                     save_stream_checkpoint)
+    from repro.session import StreamSession
+
+    users, items = _cadence_stream(n=2 * CALL)
+    first, second = (slice(0, CALL), slice(CALL, 2 * CALL))
+    live = StreamSession(_cadence_cfg())
+    assert live.ingest(users[first], items[first]).forgets == 0
+    live.checkpoint(str(tmp_path / "mid"))
+    resumed = StreamSession.restore(str(tmp_path / "mid"), _cadence_cfg())
+    a = live.ingest(users[second], items[second])
+    b = resumed.ingest(users[second], items[second])
+    assert a.forgets == b.forgets == 1
+    assert a.events_since_trigger == b.events_since_trigger == 2 * CALL - CADENCE
+    _assert_states_equal(live.states, resumed.states)
+
+    save_stream_checkpoint(str(tmp_path / "old"), CALL, live.states)
+    assert restore_stream_checkpoint(str(tmp_path / "old"),
+                                     _cadence_cfg()).since == 0
+
+
+def test_cadence_host_loop_agrees_with_scan():
+    """The host pipeline carries the cadence across calls as the scan
+    does: the same triggers per call and the same states."""
+    from repro.session import StreamSession
+
+    users, items = _cadence_stream(n=4 * CALL)
+    host = StreamSession(_cadence_cfg(backend="host"))
+    scan = StreamSession(_cadence_cfg())
+    fh, bh, sh = _ingest_calls(host, users, items)
+    fs, bs, ss = _ingest_calls(scan, users, items)
+    assert fh == fs == [0, 1, 1, 0]
+    np.testing.assert_array_equal(bh, bs)
+    assert sh == ss == 4 * CALL - 2 * CADENCE
+    _assert_states_equal(host.states, scan.states)
+
+
+def test_collision_evictions_counted_as_the_reference_counts():
+    """The telemetry's collision count is the number of live tenants a
+    colliding id replaced, counted here by a plain hash-slot replay of
+    each worker's events in stream order; host loop and scan agree, and
+    the session folds it into ``stream_collision_evictions_total``."""
+    import dataclasses
+
+    from repro.core.pipeline import run_stream
+    from repro.obs import telemetry_ints
+    from repro.session import StreamSession
+
+    cfg = dataclasses.replace(_cadence_cfg(), forgetting=ForgettingConfig())
+    users, items = _cadence_stream(n=4 * CALL)
+    g, n_i = cfg.grid.g, cfg.grid.n_i
+    h = cfg.resolved_hyper()
+    held_u, held_i, want = {}, {}, 0
+    for u, i in zip(users.tolist(), items.tolist()):
+        w = (i % n_i) * g + (u % g)
+        for held, key, ident in ((held_u, (w, (u // g) % h.u_cap), u),
+                                 (held_i, (w, (i // n_i) % h.i_cap), i)):
+            want += held.get(key, ident) != ident
+            held[key] = ident
+    assert want > 0
+
+    scan = StreamSession(cfg)
+    res = scan.ingest(users, items)
+    host = run_stream(users, items, dataclasses.replace(cfg, backend="host"))
+    assert res.dropped == 0
+    assert telemetry_ints(res.telemetry)["collisions"] == want
+    assert telemetry_ints(host.telemetry)["collisions"] == want
+    assert int(scan.metrics.counter("stream_collision_evictions_total",
+                                    "").value) == want
+
+
+def _random_state(kind: str, rng, u_cap=24, i_cap=16, k=4):
+    """A stacked two-worker state with live, empty (-1) slots and
+    arbitrary payloads."""
+    ids = lambda n: np.where(rng.random((2, n)) < 0.8,   # noqa: E731
+                             rng.integers(0, 1000, (2, n)), -1)
+    t = state_lib.Tables(
+        user_ids=jnp.asarray(ids(u_cap), jnp.int32),
+        item_ids=jnp.asarray(ids(i_cap), jnp.int32),
+        user_freq=jnp.asarray(rng.integers(0, 9, (2, u_cap)), jnp.int32),
+        item_freq=jnp.asarray(rng.integers(0, 9, (2, i_cap)), jnp.int32),
+        user_ts=jnp.asarray(rng.integers(0, 90, (2, u_cap)), jnp.int32),
+        item_ts=jnp.asarray(rng.integers(0, 90, (2, i_cap)), jnp.int32),
+        clock=jnp.asarray([90, 60], jnp.int32))
+    rated = jnp.asarray(rng.random((2, u_cap, i_cap)) < 0.5)
+    if kind == "disgd":
+        return state_lib.DisgdState(
+            tables=t, rated=rated,
+            user_vecs=jnp.asarray(rng.normal(size=(2, u_cap, k)), jnp.float32),
+            item_vecs=jnp.asarray(rng.normal(size=(2, i_cap, k)), jnp.float32))
+    return state_lib.DicsState(
+        tables=t, rated=rated,
+        co=jnp.asarray(rng.integers(0, 5, (2, i_cap, i_cap)), jnp.float32),
+        item_cnt=jnp.asarray(rng.integers(0, 9, (2, i_cap)), jnp.float32))
+
+
+def _plain_eviction(state, u_ev, i_ev):
+    """The semantics, one worker and one evicted entry at a time: id -1,
+    freq and ts 0, vector or co row, column and count zeroed, rated row
+    or column cleared."""
+    out = {k: np.array(v) for k, v in state.tables._asdict().items()}
+    rated = np.array(state.rated)
+    payload = {k: np.array(v) for k, v in state._asdict().items()
+               if k not in ("tables", "rated") and v is not None}
+    for w in range(rated.shape[0]):
+        for s in np.flatnonzero(u_ev[w]):
+            out["user_ids"][w, s] = -1
+            out["user_freq"][w, s] = out["user_ts"][w, s] = 0
+            rated[w, s, :] = False
+            if "user_vecs" in payload:
+                payload["user_vecs"][w, s] = 0
+        for s in np.flatnonzero(i_ev[w]):
+            out["item_ids"][w, s] = -1
+            out["item_freq"][w, s] = out["item_ts"][w, s] = 0
+            rated[w, :, s] = False
+            if "item_vecs" in payload:
+                payload["item_vecs"][w, s] = 0
+            if "co" in payload:
+                payload["co"][w, s, :] = payload["co"][w, :, s] = 0
+                payload["item_cnt"][w, s] = 0
+    return out, rated, payload
+
+
+@pytest.mark.parametrize("kind", ["disgd", "dics"])
+@pytest.mark.parametrize("policy", ["lru", "lfu"])
+@pytest.mark.parametrize("share", [0.0, 0.3, 1.0], ids=["none", "some", "all"])
+def test_eviction_matches_plain_semantics(kind, policy, share):
+    """``apply_forgetting`` over the worker axis (as the engine runs it)
+    on random states, with thresholds that evict none, some or all of
+    the live entries; empty slots (-1) stay as they are."""
+    import jax
+
+    rng = np.random.default_rng([7, int(share * 10), len(policy)])
+    st0 = _random_state(kind, rng)
+    t = st0.tables
+    clock = np.asarray(t.clock)[:, None]
+    if policy == "lru":
+        age = {0.0: 1000, 0.3: 60, 1.0: -1000}[share]
+        cfg = ForgettingConfig(policy="lru", lru_max_age=age)
+        u_ev = clock - np.asarray(t.user_ts) > age
+        i_ev = clock - np.asarray(t.item_ts) > age
+    else:
+        least = {0.0: 0, 0.3: 3, 1.0: 100}[share]
+        cfg = ForgettingConfig(policy="lfu", lfu_min_freq=least)
+        u_ev = np.asarray(t.user_freq) < least
+        i_ev = np.asarray(t.item_freq) < least
+    u_ev &= np.asarray(t.user_ids) >= 0
+    i_ev &= np.asarray(t.item_ids) >= 0
+    assert (u_ev.any() or i_ev.any()) == (share > 0)
+    st = jax.vmap(lambda s: apply_forgetting(s, cfg))(st0)
+    tables, rated, payload = _plain_eviction(st0, u_ev, i_ev)
+    for k, v in tables.items():
+        np.testing.assert_array_equal(np.asarray(getattr(st.tables, k)), v,
+                                      err_msg=k)
+    np.testing.assert_array_equal(np.asarray(st.rated), rated)
+    for k, v in payload.items():
+        np.testing.assert_array_equal(np.asarray(getattr(st, k)), v,
+                                      err_msg=k)
+
+
+def test_cadence_survives_rescale():
+    """The cadence belongs to the stream, not to the grid: a regrid
+    between two triggers leaves the count where it was."""
+    from repro.core.routing import GridSpec
+    from repro.session import StreamSession
+
+    users, items = _cadence_stream(n=2 * CALL)
+    session = StreamSession(_cadence_cfg())
+    assert session.ingest(users[:CALL], items[:CALL]).forgets == 0
+    session.rescale(GridSpec.rect(2, 1))
+    res = session.ingest(users[CALL:], items[CALL:])
+    assert res.forgets == 1
+    assert res.events_since_trigger == 2 * CALL - CADENCE

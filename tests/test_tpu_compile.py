@@ -198,3 +198,67 @@ def test_engine_scan_names_its_layers(one_chip, monkeypatch, algorithm):
     else:
         assert "score" in {layers[n] for n in ops_of["sort"]}
     assert "column_clear" in {layers[n] for n in ops_of["while"]}
+
+
+def _computations(text: str) -> dict:
+    """``{computation name: [instruction lines]}`` of an HLO module."""
+    import re
+
+    out, name = {}, None
+    for line in text.splitlines():
+        m = re.match(r"^(?:ENTRY )?%([\w.\-]+) .*\{$", line)
+        if m:
+            name = "ENTRY" if line.startswith("ENTRY") else m[1]
+            out[name] = []
+        elif name is not None and line.startswith("  "):
+            out[name].append(line)
+    return out
+
+
+@pytest.mark.parametrize("algorithm", ["disgd", "dics"])
+def test_engine_scan_forgets_in_place(one_chip, monkeypatch, algorithm):
+    """The scan program of a forgetting deployment (the LRU cell's
+    half-width tables) keeps one copy of the state: forgetting adds no
+    state-sized temporary, the loop copies no rated-sized table (the one
+    rated copy is the program's un-donated input, once per call), and a
+    trigger rewrites the rated table in one masked pass of the
+    ``forget`` layer."""
+    import collections
+
+    from repro.core import algorithm as algorithm_lib
+    from repro.core.forgetting import ForgettingConfig
+    from repro.obs import trace
+
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    monkeypatch.setattr(trace, "_programs", collections.deque(maxlen=16))
+    u_cap, i_cap = 19_456, 3_456
+    algo = algorithm_lib.get_algorithm(algorithm)
+    hyper = algo.default_hyper()._replace(u_cap=u_cap, i_cap=i_cap)
+    cfg = StreamConfig(algorithm=algorithm, grid=GridSpec(4),
+                       micro_batch=MB, capacity_factor=1.5, hyper=hyper,
+                       backend="pallas",
+                       forgetting=ForgettingConfig(policy="lru",
+                                                   trigger_every=5000))
+    batch_step, _, _ = engine._make_batch_step(
+        cfg, engine.make_pallas_worker_fn(cfg))
+    shapes = (jax.eval_shape(lambda: engine.init_scan_carry(cfg)),
+              (_s((2, MB), jnp.int32),) * 2)
+    compiled = engine._scan_program(batch_step).lower(*jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        shapes)).compile()
+    trace.register_program(compiled)
+    rated_bytes = cfg.grid.n_c * u_cap * i_cap
+    assert compiled.memory_analysis().temp_size_in_bytes < rated_bytes // 8
+    rated = f"pred[{cfg.grid.n_c},{u_cap},{i_cap}]"
+    layers = trace.op_layers()["jit_engine_scan"]
+    passes = []
+    for comp, lines in _computations(compiled.as_text()).items():
+        for line in lines:
+            if f"= {rated}" not in line:
+                continue
+            op = line.split(" = ", 1)[0].split("%", 1)[1]
+            if " copy(" in line:
+                assert comp == "ENTRY", line[:200]
+            if layers.get(op) == "forget" and " fusion(" in line:
+                passes.append(op)
+    assert len(passes) == 1, passes
